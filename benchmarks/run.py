@@ -13,6 +13,8 @@ import sys
 import time
 import traceback
 
+from repro import runtime
+
 from benchmarks import (
     bench_accuracy_distribution,
     bench_buffer_size,
@@ -87,6 +89,7 @@ def main():
                          "regresses >20%% below the committed "
                          "BENCH_PLANNER.json (dense-ratio normalized)")
     args = ap.parse_args()
+    runtime.use_compile_cache()
 
     if args.suite and args.suite not in {n for n, _ in SUITES}:
         # A typo here must not green-light CI with zero suites run.

@@ -1,11 +1,7 @@
-"""JAX version-compatibility shims.
+"""One spelling for the mesh and ``shard_map`` APIs this code uses.
 
-The codebase targets the modern JAX surface (``jax.shard_map``,
-``jax.sharding.AxisType``, positional ``AbstractMesh(shape, names)``)
-but must also run on the 0.4.x line this container ships, where those
-live under ``jax.experimental`` or use older signatures. Everything that
-touches a version-dependent API goes through this module so the rest of
-the code stays on one spelling.
+``jax.make_mesh`` defaults to Explicit axes; the sharded index relies on
+the partitioner propagating shardings, so meshes here take Auto axes.
 """
 
 from __future__ import annotations
@@ -14,55 +10,24 @@ import math
 
 import jax
 import numpy as np
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # 0.4.x
-    _AxisType = None
+from jax.sharding import AbstractMesh, AxisType
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_rep: bool = False):
-    """``jax.shard_map`` with graceful fallback to the experimental API.
-
-    ``check_rep`` maps onto ``check_vma`` (new) / ``check_rep`` (old) —
-    both gate the same replication-consistency check.
-    """
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check_rep)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_rep)
+    """``jax.shard_map``; ``check_rep`` maps onto ``check_vma``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep)
 
 
 def make_mesh(shape, axes, devices=None):
-    """``jax.make_mesh`` tolerant of the ``axis_types`` kwarg's absence."""
+    """``jax.make_mesh`` with Auto axes over the first prod(shape)
+    devices (or ``devices``)."""
     if devices is None:
-        n = math.prod(shape)
-        devices = np.array(jax.devices()[:n])
-    if _AxisType is not None:
-        try:
-            return jax.make_mesh(shape, axes, devices=devices,
-                                 axis_types=(_AxisType.Auto,) * len(axes))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes, devices=devices)
+        devices = np.array(jax.devices()[:math.prod(shape)])
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def abstract_mesh(shape, axes):
-    """``AbstractMesh`` across the positional-signature change.
-
-    New jax: ``AbstractMesh(axis_sizes, axis_names)``; 0.4.x:
-    ``AbstractMesh(tuple(zip(names, sizes)))``.
-    """
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    """``AbstractMesh`` from axis sizes and names."""
+    return AbstractMesh(tuple(shape), tuple(axes))

@@ -211,7 +211,7 @@ def containment_matrix(q, x, backend: str = "jnp", *, as_numpy: bool = True):
     record row of ``x`` — the single scoring door all layers share.
 
     ``backend``: "numpy" (host, dependency-free), "jnp" (XLA), or
-    "pallas" (fused TPU kernel; interpret mode off-TPU).
+    "pallas" (fused TPU kernel; interpret mode on CPU only).
     ``as_numpy=False`` keeps device backends' output on device so
     consumers (e.g. batch_query's packed thresholding) can compare
     there instead of fetching the full float matrix.

@@ -5,19 +5,20 @@ query. After postings pruning the surviving work is a *ragged* list of
 (record, query) pairs — a few hits per query at selective thresholds —
 so the verify step is a gather problem, not a sweep problem:
 
-    cand_rec i32[P]   record row to score           (scalar-prefetched)
-    cand_q   i32[P]   query row it belongs to       (scalar-prefetched)
+    cand_rec i32[P]   record row to score
+    cand_q   i32[P]   query row it belongs to
     out      f32[P]   Ĉ(Q_{cand_q[p]} → X_{cand_rec[p]})
 
-Both gathers happen *in the kernel* via scalar-prefetch BlockSpec index
-maps — the sketch matrices stay in HBM and only the addressed rows are
-DMA'd to VMEM, so the pruned path never materializes a gathered copy of
-the index. Per grid step the kernel scores one pair with exactly the
-dense kernel's math (buffer popcount + τ_pair counts + Eq. 25 tail
-estimator), reduced along the row (the segment here is one sketch row).
+The record gather happens *in the kernel*: the sketch rows (widened once
+to 128 lanes, ``lane_rows``) stay in HBM and each group of PAIRS pairs
+copies only its addressed rows into VMEM, so the pruned path never
+materializes a gathered copy of the index.
+The query side is small and resident; each pair picks its query row by
+a one-hot select. Per pair the kernel runs exactly the dense kernel's
+math (buffer popcount + τ_pair counts + Eq. 25 tail estimator).
 
 ``score_pairs`` is the public door with the repo-standard ``backend=``
-switch: "pallas" (this kernel, interpret mode off-TPU), "jnp" (XLA
+switch: "pallas" (this kernel, interpret mode on CPU), "jnp" (XLA
 gather + vectorized pair math), "numpy" (host oracle).
 """
 
@@ -33,103 +34,139 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hashing import PAD, TWO32
+from repro.kernels import interpret_mode
+from repro.kernels.gbkmv_score import hash_key, pair_score, popcount_rows
 
-# Lane-aligned membership chunk (matches gbkmv_score.QCHUNK).
-QCHUNK = 128
+# Candidate pairs per grid step (the pair ids ride in SMEM blocks, which
+# must match XLA's 1024-element tiling of an int32 vector) and per inner
+# group: one (8, C) tile of rows.
+STEP = 1024
+PAIRS = 8
 
 
 def _pair_kernel(
-    cand_rec_ref,   # i32[P]   (scalar prefetch)
-    cand_q_ref,     # i32[P]   (scalar prefetch)
-    x_values_ref,   # u32[1, C]   gathered record row
-    x_thresh_ref,   # u32[1, 1]
-    x_buf_ref,      # u32[1, W]
-    q_values_ref,   # u32[1, Cq]  gathered query row
-    q_thresh_ref,   # u32[1, 1]
-    q_buf_ref,      # u32[1, W]
-    q_sizes_ref,    # i32[1, 1]
-    out_ref,        # f32[1, 1]
+    rec_ref,        # i32[STEP]       SMEM: record row of each pair
+    qi_ref,         # i32[STEP]       SMEM: query row of each pair
+    x_values_hbm,   # u32[M, Cp]      HBM, PAD-padded to 128-lane rows
+    x_thresh_ref,   # u32[STEP, 1]    gathered per pair
+    x_buf_ref,      # u32[STEP, W]    gathered per pair
+    q_cols_ref,     # i32[Cq, Gq]     query keys, one column per query
+    q_rows_ref,     # i32[Gq, Cq]     the same keys, one row per query
+    q_thresh_ref,   # i32[Gq]         SMEM, keys
+    q_buf_ref,      # u32[Gq, W]
+    q_sizes_ref,    # i32[Gq]         SMEM
+    out_ref,        # f32[STEP, 1]
+    rows,           # u32[PAIRS, 1, Cp] VMEM scratch
+    sem,            # DMA semaphores [PAIRS]
 ):
-    xv = x_values_ref[...]                    # [1, C]
-    xt = x_thresh_ref[...][:, 0]              # [1]
-    qv = q_values_ref[0, :]                   # [Cq]
-    qt = q_thresh_ref[0, 0]
-    qs = q_sizes_ref[0, 0]
-    _, c = xv.shape
-    cq = qv.shape[0]
+    gq = q_rows_ref.shape[0]
+    lane_g = lax.broadcasted_iota(jnp.int32, (1, gq), 1)
+    row_g = lax.broadcasted_iota(jnp.int32, (gq, 1), 0)
+    row_p = lax.broadcasted_iota(jnp.int32, (PAIRS, 1), 0)
 
-    tau = jnp.minimum(xt, qt)                 # [1]
-    live_x = xv <= tau[:, None]               # [1, C]
-    nx = jnp.sum(live_x.astype(jnp.int32), axis=-1)
-    live_q = qv[None, :] <= tau[:, None]      # [1, Cq]
-    nq = jnp.sum(live_q.astype(jnp.int32), axis=-1)
+    def group(j, carry):
+        base = pl.multiple_of(j * PAIRS, PAIRS)
+        copies = []
+        for s in range(PAIRS):
+            cp = pltpu.make_async_copy(
+                x_values_hbm.at[pl.ds(rec_ref[base + s], 1), :],
+                rows.at[s], sem.at[s])
+            cp.start()
+            copies.append(cp)
+        x_thr = hash_key(x_thresh_ref[pl.ds(base, PAIRS), :])     # [8, 1]
+        x_bufs = lax.bitcast_convert_type(
+            x_buf_ref[pl.ds(base, PAIRS), :], jnp.int32)          # [8, W]
+        q_bufs = lax.bitcast_convert_type(q_buf_ref[...], jnp.int32)
+        scores = jnp.zeros((PAIRS, 1), jnp.float32)
+        for s in range(PAIRS):
+            g = qi_ref[base + s]
+            # One-hot selects of the pair's query (one term is kept).
+            qcol = jnp.sum(jnp.where(lane_g == g, q_cols_ref[...], 0),
+                           axis=1, keepdims=True)                 # [Cq, 1]
+            qv = jnp.sum(jnp.where(row_g == g, q_rows_ref[...], 0),
+                         axis=0, keepdims=True)                   # [1, Cq]
+            qb = jnp.sum(jnp.where(row_g == g, q_bufs, 0), axis=0,
+                         keepdims=True)                           # [1, W]
+            copies[s].wait()
+            xv = hash_key(rows[s])                                # [1, C]
+            tau = jnp.minimum(x_thr[s:s + 1, :], q_thresh_ref[g])
+            live_x = xv <= tau
+            live_q = qv <= tau
+            member = jnp.max((qcol == xv).astype(jnp.int32), axis=0,
+                             keepdims=True)                       # [1, C]
+            kcap = jnp.sum(member * live_x.astype(jnp.int32), axis=1,
+                           keepdims=True)
+            o1 = popcount_rows(x_bufs[s:s + 1, :], qb)
+            score = pair_score(xv, live_x, qv, live_q, kcap, o1,
+                               q_sizes_ref[g])
+            scores = jnp.where(row_p == s, score, scores)
+        out_ref[pl.ds(base, PAIRS), :] = scores
+        return carry
 
-    def mem_body(i, member):
-        chunk = lax.dynamic_slice(qv, (i * QCHUNK,), (QCHUNK,))
-        hit = jnp.any(xv[:, :, None] == chunk[None, None, :], axis=-1)
-        return member | hit
+    lax.fori_loop(0, STEP // PAIRS, group, 0)
 
-    member = lax.fori_loop(
-        0, cq // QCHUNK, mem_body, jnp.zeros((1, c), jnp.bool_)
-    )
-    kcap = jnp.sum((member & live_x).astype(jnp.int32), axis=-1)
-    k = nx + nq - kcap
 
-    ux = jnp.max(jnp.where(live_x, xv, jnp.uint32(0)), axis=-1)
-    uq = jnp.max(jnp.where(live_q, qv[None, :], jnp.uint32(0)), axis=-1)
-    u = jnp.maximum(ux, uq)
-    u_unit = (u.astype(jnp.float32) + 1.0) / TWO32
-
-    kf = k.astype(jnp.float32)
-    d_hat = (kcap.astype(jnp.float32) / jnp.maximum(kf, 1.0)) * (
-        (kf - 1.0) / jnp.maximum(u_unit, 1e-30)
-    )
-    d_hat = jnp.where((k >= 2) & (kcap >= 1), d_hat,
-                      jnp.where(kcap >= 1, kcap.astype(jnp.float32), 0.0))
-
-    o1 = jnp.sum(lax.population_count(x_buf_ref[...] & q_buf_ref[...]),
-                 axis=-1)
-    out_ref[0, 0] = ((o1.astype(jnp.float32) + d_hat) / jnp.maximum(
-        qs.astype(jnp.float32), 1.0))[0]
+@jax.jit
+def lane_rows(x_values):
+    """The record rows as the pair kernel reads them: widened with PAD
+    to whole 128-lane rows (a row copy moves whole rows; PAD lanes are
+    never live). A resident index builds this once, not per call."""
+    c = x_values.shape[1]
+    return jnp.pad(x_values, ((0, 0), (0, -(-c // 128) * 128 - c)),
+                   constant_values=PAD)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _gather_score_pallas(
-    x_values, x_thresh, x_buf,
+    x_rows, x_thresh, x_buf,
     q_values, q_thresh, q_buf, q_sizes,
     cand_rec, cand_q,
-    *, interpret: bool = False,
+    *, interpret: bool,
 ):
-    """One grid step per candidate pair; rows addressed via prefetch."""
-    _, c = x_values.shape
-    _, cq = q_values.shape
+    """STEP candidate pairs per grid step. The record rows (``lane_rows``)
+    stay in HBM and only the addressed rows are copied into VMEM; the
+    small per-pair columns (thresholds, bitmaps) are gathered by XLA."""
+    cp_ = x_rows.shape[1]
+    assert cp_ % 128 == 0, "record rows must come from lane_rows"
+    gq, cq = q_values.shape
     w = x_buf.shape[1]
     p = cand_rec.shape[0]
-    assert cq % QCHUNK == 0 and w >= 1 and w == q_buf.shape[1]
+    assert w >= 1 and w == q_buf.shape[1]
+    pp = -(-p // STEP) * STEP
+    # Padding pairs score (record 0, query 0) and are sliced off.
+    cand_rec = jnp.pad(cand_rec, (0, pp - p))
+    cand_q = jnp.pad(cand_q, (0, pp - p))
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(p,),
-        in_specs=[
-            pl.BlockSpec((1, c), lambda i, rec, q: (rec[i], 0)),
-            pl.BlockSpec((1, 1), lambda i, rec, q: (rec[i], 0)),
-            pl.BlockSpec((1, w), lambda i, rec, q: (rec[i], 0)),
-            pl.BlockSpec((1, cq), lambda i, rec, q: (q[i], 0)),
-            pl.BlockSpec((1, 1), lambda i, rec, q: (q[i], 0)),
-            pl.BlockSpec((1, w), lambda i, rec, q: (q[i], 0)),
-            pl.BlockSpec((1, 1), lambda i, rec, q: (q[i], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, rec, q: (i, 0)),
-    )
+    q_keys = hash_key(q_values)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    per_pair = pl.BlockSpec((STEP,), lambda i: (i,),
+                            memory_space=pltpu.SMEM)
     out = pl.pallas_call(
         _pair_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((p, 1), jnp.float32),
+        grid=(pp // STEP,),
+        in_specs=[
+            per_pair,
+            per_pair,
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((STEP, 1), lambda i: (i, 0)),
+            pl.BlockSpec((STEP, w), lambda i: (i, 0)),
+            pl.BlockSpec((cq, gq), lambda i: (0, 0)),
+            pl.BlockSpec((gq, cq), lambda i: (0, 0)),
+            smem,
+            pl.BlockSpec((gq, w), lambda i: (0, 0)),
+            smem,
+        ],
+        out_specs=pl.BlockSpec((STEP, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((pp, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((PAIRS, 1, cp_), jnp.uint32),
+                        pltpu.SemaphoreType.DMA((PAIRS,))],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(cand_rec, cand_q,
-      x_values, x_thresh[:, None], x_buf,
-      q_values, q_thresh[:, None], q_buf, q_sizes[:, None])
-    return out[:, 0]
+    )(cand_rec, cand_q, x_rows, x_thresh[cand_rec][:, None],
+      x_buf[cand_rec], q_keys.T, q_keys, hash_key(q_thresh), q_buf,
+      q_sizes.astype(jnp.int32))
+    return out[:p, 0]
 
 
 @jax.jit
@@ -249,10 +286,13 @@ def score_pairs(
     """f32[P] pair scores for a ragged candidate list.
 
     ``x`` / ``q`` are PackedSketches (record index / query batch pack,
-    buffer widths already aligned). ``cand_rec[p]`` indexes x rows,
-    ``cand_q[p]`` indexes q rows. Device paths pad P to a power-of-two
-    bucket (extra pairs repeat pair 0 and are sliced off) so steady-state
-    serving reuses a handful of compiled shapes.
+    buffer widths already aligned) on one device or the host; a host
+    pack crosses to the device on every call. ``cand_rec[p]`` indexes x
+    rows, ``cand_q[p]`` indexes q rows. Device paths pad P to a
+    power-of-two bucket (extra pairs repeat pair 0 and are sliced off)
+    so steady-state serving reuses a handful of compiled shapes. A
+    record-sharded index scores its pairs shard by shard
+    (``repro.sketchindex.distributed``).
     """
     from repro.core.estimators import normalize_backend
 
@@ -285,20 +325,18 @@ def score_pairs(
     qs = jnp.asarray(q.sizes, jnp.int32)
 
     if backend == "pallas":
-        from repro.kernels.ops import _on_tpu, _pad_axis
+        from repro.kernels.ops import _pad_axis
 
         if interpret is None:
-            interpret = not _on_tpu()
-        qv = _pad_axis(qv, 1, QCHUNK, PAD)
+            interpret = interpret_mode()
         w = max(xb.shape[1], qb.shape[1], 1)
         xb = _pad_axis(xb if xb.shape[1] else
                        jnp.zeros((xb.shape[0], 1), jnp.uint32), 1, w, 0)
         qb = _pad_axis(qb if qb.shape[1] else
                        jnp.zeros((qb.shape[0], 1), jnp.uint32), 1, w, 0)
         out = _gather_score_pallas(
-            xv, xt, xb, qv, qt, qb, qs,
-            jnp.asarray(cand_rec), jnp.asarray(cand_q),
-            interpret=interpret)
+            lane_rows(xv), xt, xb, qv, qt, qb, qs,
+            jnp.asarray(cand_rec), jnp.asarray(cand_q), interpret=interpret)
     else:
         out = _gather_score_jnp(
             xv, xt, xb, qv, qt, qb, qs,

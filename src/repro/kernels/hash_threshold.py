@@ -31,6 +31,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.hashing import PAD, hash_u32
+from repro.kernels import interpret_mode
 
 LANES = 128
 
@@ -48,7 +49,7 @@ def _hash_kernel(seed_ref, tau_ref, ids_ref, h_ref, keep_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def hash_threshold(ids2d, seed, tau, *, block_rows: int = 8, interpret: bool = False):
+def hash_threshold(ids2d, seed, tau, *, block_rows: int = 8, interpret: bool):
     """ids2d u32[R, 128] → (hashes u32[R, 128], keep i32[R, 128]).
 
     ops.py reshapes/pads flat id streams into the [R, LANES] view.
@@ -81,10 +82,6 @@ def hash_threshold(ids2d, seed, tau, *, block_rows: int = 8, interpret: bool = F
 # ---------------------------------------------------------------------------
 # Fused device-path sketch construction (hash → τ → sort → pack)
 # ---------------------------------------------------------------------------
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _hash_flat(ids, seed, *, use_pallas: bool, interpret: bool):
@@ -193,7 +190,7 @@ def fused_build_columns(batch, tail_mask, budget: int, *, seed: int = 0,
         raise ValueError(f"tau_mode must be one of {TAU_MODES}, "
                          f"got {tau_mode!r}")
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     tail_mask = np.asarray(tail_mask, bool)
     ids = np.asarray(batch.ids)[tail_mask]
     row = batch.row_index()[tail_mask]

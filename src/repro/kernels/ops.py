@@ -1,23 +1,18 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Handles padding/alignment so callers pass natural shapes, and switches to
-``interpret=True`` automatically off-TPU (this container is CPU-only; the
-kernels are written for TPU and *validated* in interpret mode).
+Handles padding/alignment so callers pass natural shapes. Interpret mode
+is decided by :func:`repro.kernels.interpret_mode` (the CPU backend only)
+unless a caller asks for it explicitly.
 """
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core.hashing import PAD
 from repro.kernels import gbkmv_score as _score_mod
 from repro.kernels import hash_threshold as _hash_mod
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels import interpret_mode
 
 
 def _pad_axis(a, axis, mult, fill):
@@ -37,29 +32,29 @@ def score_index(
 ):
     """Containment scores f32[M, Gq] of a query batch against the index.
 
-    Pads records to block_m, query capacity to the 128-lane membership
-    chunk, and guarantees ≥1 buffer word (zero word == empty buffer).
+    Pads records to block_m and guarantees ≥1 buffer word (zero word ==
+    empty buffer).
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     m = x_values.shape[0]
 
     x_values = _pad_axis(jnp.asarray(x_values, jnp.uint32), 0, block_m, PAD)
     # Padded records: threshold 0 → nothing live → score 0.
-    x_thresh = _pad_axis(jnp.asarray(x_thresh, jnp.uint32)[:, None], 0, block_m, 0)
+    x_thresh = _pad_axis(jnp.asarray(x_thresh, jnp.uint32), 0, block_m, 0)
     x_buf = jnp.asarray(x_buf, jnp.uint32)
     if x_buf.shape[1] == 0:
         x_buf = jnp.zeros((x_buf.shape[0], 1), jnp.uint32)
     x_buf = _pad_axis(x_buf, 0, block_m, 0)
 
-    q_values = _pad_axis(jnp.asarray(q_values, jnp.uint32), 1, _score_mod.QCHUNK, PAD)
-    q_thresh = jnp.asarray(q_thresh, jnp.uint32)[:, None]
+    q_values = jnp.asarray(q_values, jnp.uint32)
+    q_thresh = jnp.asarray(q_thresh, jnp.uint32)
     q_buf = jnp.asarray(q_buf, jnp.uint32)
     if q_buf.shape[1] == 0:
         q_buf = jnp.zeros((q_buf.shape[0], 1), jnp.uint32)
-    q_sizes = jnp.asarray(q_sizes, jnp.int32)[:, None]
+    q_sizes = jnp.asarray(q_sizes, jnp.int32)
 
-    # Align x capacity with nothing (C free); align buffer widths.
+    # Align buffer widths.
     w = max(x_buf.shape[1], q_buf.shape[1])
     x_buf = _pad_axis(x_buf, 1, w, 0)
     q_buf = _pad_axis(q_buf, 1, w, 0)
@@ -74,7 +69,7 @@ def score_index(
 def hash_and_filter(ids, seed: int, tau, *, interpret: bool | None = None):
     """(hashes u32[N], keep bool[N]) for a flat element-id stream."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     ids = jnp.asarray(ids)
     n = ids.shape[0]
     lanes = _hash_mod.LANES

@@ -9,7 +9,7 @@ arena's blocked tail mirror, all inside ONE jitted program per output
 mode:
 
     probe    for every query hash, its postings row (index + existence)
-             — a chunked compare against the sorted key column
+             — a windowed compare against the sorted key column
              (Pallas kernel for ``backend="pallas"``, XLA searchsorted
              for ``backend="jnp"``). The block-header probe the host
              planner used to run (row_blocks ranges per matched key)
@@ -24,11 +24,12 @@ mode:
              recompiles.
     decode   each task's block body → up to 128 record ids. Sparse
              bodies unpack their bitpacked deltas and prefix-sum back to
-             ids (the Pallas block-decode kernel for ``"pallas"`` — one
-             task per grid step, one dynamic-slice DMA of the body, a
-             one-hot word select instead of a data-dependent gather — or
-             a vectorized jnp twin). The rare dense-bitmap bodies run in
-             a SECOND while_loop over a dense-task-only stream (their
+             ids (the Pallas block-decode kernel for ``"pallas"`` — eight
+             tasks per grid step, one DMA of the two payload rows that
+             hold each body, a one-hot word select instead of a
+             data-dependent gather — or a vectorized jnp twin). The rare
+             dense-bitmap bodies run in a SECOND while_loop over a
+             dense-task-only stream (their
              rank-select materializes a [dchunk, 3968] bit matrix — far
              too hot to pay per sparse chunk): a cumulative dense-kind
              count over the mirrored block metadata locates the j-th
@@ -80,17 +81,25 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hashing import PAD, TWO32
+from repro.kernels.gbkmv_score import hash_key
 from repro.planner.postings import BLOCK, DENSE_MAX_WORDS
 
-# Probe kernel tiling: query hashes per grid step / key-column chunk.
+_PAD_KEY = 0x7FFFFFFF          # hash_key(PAD)
+
+# Probe kernel tiling: query hashes per grid step (a column) / key rows
+# of 128 lanes per grid step along the key column.
 QBLOCK = 256
-KCHUNK = 512
+KROWS = 32
 # Sparse block bodies span at most ceil(127·31/32) = 124 payload words;
 # one 128-word window therefore always covers a body (plus slack the
-# payload is padded with), so the decode kernel's DMA has a static size.
+# payload is padded with). The decode kernel copies the two 128-word
+# payload rows that hold the window, so its DMA has a static size.
 DECODE_WINDOW = 128
+# Block-decode tasks per grid step: one (8, 128) output tile.
+DECODE_TASKS = 8
 # while_loop window sizes: sparse block tasks / dense block tasks per
 # iteration. Fixed static shapes inside a data-dependent trip count —
 # the whole point: one compiled program for any batch. Each window
@@ -101,63 +110,76 @@ TASK_CHUNK = 128
 DENSE_TASK_CHUNK = 64
 
 
-def _probe_kernel(keys_ref, q_ref, pos_ref, hit_ref):
+def _probe_kernel(keys_ref, q_ref, pos_ref, hit_ref, pos_acc, hit_acc):
     """pos = #keys < q, hit = any(keys == q), per query hash.
 
-    ``keys_ref`` u32[1, Up] (whole padded key column, VMEM-resident),
-    ``q_ref`` u32[1, QBLOCK]. Chunked compare instead of binary search:
-    contiguous loads, no data-dependent addressing — the layout TPUs
-    like. Key padding is PAD, which never matches a real hash and is
-    masked for the (PAD == PAD) query-padding case below.
+    Grid (query block i, key block j). ``keys_ref`` u32[KROWS, 128] is
+    the j-th window of the padded key column — the pipeline streams the
+    column from HBM window by window, so no grid step holds more than
+    one window in VMEM. ``q_ref`` u32[QBLOCK, 1] is a column of query
+    hashes. Each key row is compared against the whole column at once
+    and the [QBLOCK, 128] partial counts reduce along the lanes only
+    after the last window: contiguous loads, no data-dependent
+    addressing. Key padding is PAD, which is never below a query hash
+    and never matches a real one; the (PAD == PAD) query-padding case
+    is masked at the end.
     """
-    q = q_ref[0, :]                                     # [B]
+    j = pl.program_id(1)
 
-    def body(i, carry):
-        pos, hit = carry
-        chunk = lax.dynamic_slice(keys_ref[...], (0, i * KCHUNK),
-                                  (1, KCHUNK))[0]       # [KCHUNK]
-        pos = pos + jnp.sum(
-            (chunk[None, :] < q[:, None]).astype(jnp.int32), axis=-1)
-        hit = hit | jnp.any(chunk[None, :] == q[:, None], axis=-1)
-        return pos, hit
+    @pl.when(j == 0)
+    def _():
+        pos_acc[...] = jnp.zeros_like(pos_acc)
+        hit_acc[...] = jnp.zeros_like(hit_acc)
 
-    b = q.shape[0]
-    up = keys_ref.shape[1]
-    pos, hit = lax.fori_loop(
-        0, up // KCHUNK, body,
-        (jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.bool_)))
-    hit = hit & (q != PAD)
-    pos_ref[0, :] = pos
-    hit_ref[0, :] = hit.astype(jnp.int32)
+    q = hash_key(q_ref[...])                            # [QBLOCK, 1]
+    keys = hash_key(keys_ref[...])                      # [KROWS, 128]
+    pos, hit = pos_acc[...], hit_acc[...]
+    for r in range(keys.shape[0]):
+        row = keys[r:r + 1, :]
+        pos = pos + (row < q).astype(jnp.int32)
+        hit = jnp.maximum(hit, (row == q).astype(jnp.int32))
+    pos_acc[...] = pos
+    hit_acc[...] = hit
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        pos_ref[...] = jnp.sum(pos, axis=1, keepdims=True)
+        hit_ref[...] = jnp.max(hit, axis=1, keepdims=True) * (
+            q != _PAD_KEY).astype(jnp.int32)
 
 
 def _probe_pallas(keys, q_flat, *, interpret: bool):
     """(pos i32[n], hit bool[n]) for a flat query-hash vector."""
     n = q_flat.shape[0]
     npad = -(-n // QBLOCK) * QBLOCK
-    q2 = jnp.pad(q_flat, (0, npad - n), constant_values=PAD)[None, :]
+    q2 = jnp.pad(q_flat, (0, npad - n), constant_values=PAD)[:, None]
     u = keys.shape[0]
-    upad = max(-(-u // KCHUNK) * KCHUNK, KCHUNK)
-    k2 = jnp.pad(keys, (0, upad - u), constant_values=PAD)[None, :]
+    window = KROWS * 128
+    upad = max(-(-u // window), 1) * window
+    k2 = jnp.pad(keys, (0, upad - u), constant_values=PAD).reshape(-1, 128)
 
     pos, hit = pl.pallas_call(
         _probe_kernel,
-        grid=(npad // QBLOCK,),
+        grid=(npad // QBLOCK, upad // window),
         in_specs=[
-            pl.BlockSpec((1, upad), lambda i: (0, 0)),
-            pl.BlockSpec((1, QBLOCK), lambda i: (0, i)),
+            pl.BlockSpec((KROWS, 128), lambda i, j: (j, 0)),
+            pl.BlockSpec((QBLOCK, 1), lambda i, j: (i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, QBLOCK), lambda i: (0, i)),
-            pl.BlockSpec((1, QBLOCK), lambda i: (0, i)),
+            pl.BlockSpec((QBLOCK, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((QBLOCK, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((1, npad), jnp.int32),
-            jax.ShapeDtypeStruct((1, npad), jnp.int32),
+            jax.ShapeDtypeStruct((npad, 1), jnp.int32),
+            jax.ShapeDtypeStruct((npad, 1), jnp.int32),
         ],
+        scratch_shapes=[pltpu.VMEM((QBLOCK, 128), jnp.int32),
+                        pltpu.VMEM((QBLOCK, 128), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(k2, q2)
-    return pos[0, :n], hit[0, :n].astype(jnp.bool_)
+    return pos[:n, 0], hit[:n, 0].astype(jnp.bool_)
 
 
 def _probe_jnp(keys, q_flat):
@@ -175,67 +197,89 @@ def _probe_jnp(keys, q_flat):
 
 
 def _block_decode_kernel(first_ref, off_ref, bw_ref, cnt_ref,
-                         payload_ref, out_ref):
-    """Decode ONE sparse block task per grid step.
+                         payload_ref, out_ref, window, sem):
+    """Decode DECODE_TASKS sparse block tasks per grid step.
 
-    One dynamic-slice DMA pulls the block's ``DECODE_WINDOW``-word body
-    out of the payload column; deltas unpack via a one-hot word select
-    (a [127, 128] masked max — VPU-shaped work, no data-dependent
-    addressing) and a prefix sum turns them back into record ids. All
-    arithmetic is 32-bit: the two straddled words recombine with
-    shift-or instead of a 64-bit widen, because TPUs would rather not.
-    Lanes past the block's count carry garbage and are masked by the
-    caller (shared with the jnp twin).
+    The per-task scalars arrive by scalar prefetch (SMEM). The payload
+    stays in HBM: each task copies the two 128-word rows that hold its
+    body into VMEM, all copies in flight at once. Deltas unpack via a
+    one-hot word select (delta index on the sublanes, payload word on
+    the lanes: a masked lane sum, no data-dependent addressing) and a
+    triangular masked sum along the sublanes turns them back into record
+    ids, already laid out as one output row. All arithmetic is 32-bit:
+    the two straddled words recombine with shift-or instead of a 64-bit
+    widen. Lanes past the block's count carry values the caller masks
+    (the same values as the jnp twin's).
     """
-    first = first_ref[0, 0]
-    off = off_ref[0, 0]
-    bw = bw_ref[0, 0].astype(jnp.uint32)
-    cnt = cnt_ref[0, 0]
+    i = pl.program_id(0)
+    copies = []
+    for s in range(DECODE_TASKS):
+        row = off_ref[i * DECODE_TASKS + s] >> 7
+        cp = pltpu.make_async_copy(payload_ref.at[pl.ds(row, 2), :],
+                                   window.at[s], sem.at[s])
+        cp.start()
+        copies.append(cp)
 
-    body = lax.dynamic_slice(payload_ref[...], (0, off),
-                             (1, DECODE_WINDOW))            # u32[1, W]
-    p = lax.broadcasted_iota(jnp.int32, (1, BLOCK - 1), 1)  # [1, 127]
-    bitpos = p * bw_ref[0, 0]
-    widx = bitpos >> 5
-    lanes = lax.broadcasted_iota(jnp.int32, (1, DECODE_WINDOW), 1)
-    sel0 = widx[0][:, None] == lanes[0][None, :]            # [127, W]
-    sel1 = (widx[0] + 1)[:, None] == lanes[0][None, :]
-    w0 = jnp.max(jnp.where(sel0, body[0][None, :], jnp.uint32(0)), axis=1)
-    w1 = jnp.max(jnp.where(sel1, body[0][None, :], jnp.uint32(0)), axis=1)
+    p = lax.broadcasted_iota(jnp.int32, (BLOCK, 1), 0)      # delta index
+    lanes = lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    tri = p < lax.broadcasted_iota(jnp.int32, (BLOCK, BLOCK), 1)
 
-    sh = (bitpos[0] & 31).astype(jnp.uint32)
-    lo = w0 >> sh
-    hi = jnp.where(sh > 0,
-                   w1 << ((jnp.uint32(32) - sh) & jnp.uint32(31)),
-                   jnp.uint32(0))
-    mask = jnp.where(bw > 0, (jnp.uint32(1) << bw) - jnp.uint32(1),
-                     jnp.uint32(0))
-    v = ((lo | hi) & mask).astype(jnp.int32)
-    v = jnp.where(p[0] < cnt - 1, v, 0)[None, :]
-    ids = first + jnp.concatenate(
-        [jnp.zeros((1, 1), jnp.int32), jnp.cumsum(v, axis=1)], axis=1)
-    out_ref[0, :] = ids[0]
+    def word(w, body):
+        """body word ``w`` per sublane ([BLOCK, 1] indices < 256)."""
+        pick = (jnp.where(w == lanes, body[0:1, :], 0)
+                + jnp.where(w - 128 == lanes, body[1:2, :], 0))
+        return jnp.sum(pick, axis=1, keepdims=True)
+
+    for s in range(DECODE_TASKS):
+        t = i * DECODE_TASKS + s
+        copies[s].wait()
+        body = lax.bitcast_convert_type(window[s], jnp.int32)   # [2, 128]
+        bw = bw_ref[t]
+        bitpos = p * bw
+        w = (off_ref[t] & 127) + (bitpos >> 5)
+        sh = bitpos & 31
+        lo = lax.shift_right_logical(word(w, body), sh)
+        hi = jnp.where(sh > 0, word(w + 1, body) << ((32 - sh) & 31), 0)
+        mask = jnp.where(bw > 0, (1 << bw) - 1, 0)
+        v = jnp.where(p < cnt_ref[t] - 1, (lo | hi) & mask, 0)  # [BLOCK, 1]
+        ids = first_ref[t] + jnp.sum(jnp.where(tri, v, 0), axis=0,
+                                     keepdims=True)             # [1, BLOCK]
+        out_ref[s:s + 1, :] = ids
 
 
-def _decode_sparse_pallas(first, off, bw, cnt, payload, *, interpret: bool):
-    """i32[tb, BLOCK] raw sparse-decoded ids (Pallas, one task/step)."""
+def _decode_payload_rows(payload):
+    """u32[R, 128] view of a payload for the decode kernel: padded so the
+    two rows after any offset ≤ len(payload) exist."""
+    rows = payload.shape[0] // 128 + 2
+    return jnp.pad(payload, (0, rows * 128 - payload.shape[0])).reshape(
+        rows, 128)
+
+
+def _decode_sparse_pallas(first, off, bw, cnt, payload_rows, *,
+                          interpret: bool):
+    """i32[tb, BLOCK] raw sparse-decoded ids (Pallas, DECODE_TASKS tasks
+    per step). ``payload_rows`` is :func:`_decode_payload_rows`' view."""
     tb = first.shape[0]
-    out = pl.pallas_call(
+    tp = -(-tb // DECODE_TASKS) * DECODE_TASKS
+    first, off, bw, cnt = (jnp.pad(a, (0, tp - tb))
+                           for a in (first, off, bw, cnt))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(tp // DECODE_TASKS,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((DECODE_TASKS, BLOCK),
+                               lambda i, *_: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((DECODE_TASKS, 2, 128), jnp.uint32),
+                        pltpu.SemaphoreType.DMA((DECODE_TASKS,))],
+    )
+    return pl.pallas_call(
         _block_decode_kernel,
-        grid=(tb,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, payload.shape[0]), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((tb, BLOCK), jnp.int32),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((tp, BLOCK), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(first[:, None], off[:, None], bw[:, None], cnt[:, None],
-      payload[None, :])
-    return out
+    )(first, off, bw, cnt, payload_rows)[:tb]
 
 
 def _decode_sparse_jnp(first, off, bw, cnt, payload):
@@ -412,6 +456,7 @@ def _pipeline_scores(keys, row_blocks, blk_first, blk_meta, blk_off,
     off_s = jnp.concatenate([blk_off, blk_off[-1:]])
     pay = jnp.pad(payload, (0, DECODE_WINDOW)) if payload.shape[0] \
         else jnp.zeros(DECODE_WINDOW, jnp.uint32)
+    pay_rows = _decode_payload_rows(payload) if backend == "pallas" else None
 
     nseg = max(nflat - 1, 0)
     cqd = jnp.int32(max(cq, 1))
@@ -435,8 +480,8 @@ def _pipeline_scores(keys, row_blocks, blk_first, blk_meta, blk_off,
         t_kind = ((t_meta >> jnp.uint32(13)) & jnp.uint32(1)).astype(
             jnp.int32)
         if backend == "pallas":
-            ids = _decode_sparse_pallas(t_first, t_off, t_bw, t_cnt, pay,
-                                        interpret=interpret)
+            ids = _decode_sparse_pallas(t_first, t_off, t_bw, t_cnt,
+                                        pay_rows, interpret=interpret)
         else:
             ids = _decode_sparse_jnp(t_first, t_off, t_bw, t_cnt, pay)
         # Dense tasks are the dense loop's; sentinel/invalid lanes carry
@@ -501,7 +546,7 @@ def fused_scores(keys, row_blocks, blk_first, blk_meta, blk_off, payload,
                  x_values, x_thresh, x_buf, qblob,
                  *, gq: int, cq: int, w: int,
                  chunk: int = TASK_CHUNK, dchunk: int = DENSE_TASK_CHUNK,
-                 m: int, backend: str = "jnp", interpret: bool = True,
+                 m: int, backend: str = "jnp", interpret: bool,
                  has_dense: bool = True):
     """f32[m, Gq] device score matrix (parity/bench seam)."""
     q_values, q_thresh, q_buf, q_sizes, _ = _carve_query_blob(
@@ -520,7 +565,7 @@ def fused_hit_words(keys, row_blocks, blk_first, blk_meta, blk_off, payload,
                     *, gq: int, cq: int, w: int,
                     chunk: int = TASK_CHUNK,
                     dchunk: int = DENSE_TASK_CHUNK, m: int,
-                    backend: str = "jnp", interpret: bool = True,
+                    backend: str = "jnp", interpret: bool,
                     has_dense: bool = True):
     """u32[ceil(m/32), Gq] packed hit words: bit ``i & 31`` of word
     ``i >> 5`` is (score[i, g] >= thresholds[g]). The float32-exact
@@ -549,7 +594,7 @@ def fused_topk(keys, row_blocks, blk_first, blk_meta, blk_off, payload,
                *, k: int, gq: int, cq: int, w: int,
                chunk: int = TASK_CHUNK,
                dchunk: int = DENSE_TASK_CHUNK, m: int,
-               backend: str = "jnp", interpret: bool = True,
+               backend: str = "jnp", interpret: bool,
                has_dense: bool = True):
     """(scores f32[Gq, k], ids i32[Gq, k]) device top-k over the fused
     score matrix. ``lax.top_k`` ranks equal values lower-index-first —

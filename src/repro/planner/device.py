@@ -218,8 +218,7 @@ def pruned_scores(dpost, dpack, sq: StagedQuery, *, m: int, backend: str):
     and the closed-form estimator are one jitted call over
     already-resident inputs (kernels/postings_merge.fused_scores).
     """
-    from repro.kernels import postings_merge
-    from repro.kernels.ops import _on_tpu
+    from repro.kernels import interpret_mode, postings_merge
 
     _note_call(_sig("scores", dpost, sq, m=m, backend=backend))
     with _quiet():
@@ -228,7 +227,7 @@ def pruned_scores(dpost, dpack, sq: StagedQuery, *, m: int, backend: str):
             dpost.off, dpost.payload,
             dpack.values, dpack.thresh, dpack.buf, sq.blob,
             gq=sq.gq, cq=sq.cq, w=sq.w,
-            m=m, backend=backend, interpret=not _on_tpu(),
+            m=m, backend=backend, interpret=interpret_mode(),
             has_dense=dpost.has_dense)
 
 
@@ -238,8 +237,7 @@ def fused_mask_words(dpost, dpack, sq: StagedQuery, *, m: int,
     score → float32-exact packed thresholding with no host transfer
     anywhere in between (the staged blob already encodes the cut).
     """
-    from repro.kernels import postings_merge
-    from repro.kernels.ops import _on_tpu
+    from repro.kernels import interpret_mode, postings_merge
 
     _note_call(_sig("mask", dpost, sq, m=m, backend=backend))
     with _quiet():
@@ -248,7 +246,7 @@ def fused_mask_words(dpost, dpack, sq: StagedQuery, *, m: int,
             dpost.off, dpost.payload,
             dpack.values, dpack.thresh, dpack.buf, sq.blob,
             gq=sq.gq, cq=sq.cq, w=sq.w,
-            m=m, backend=backend, interpret=not _on_tpu(),
+            m=m, backend=backend, interpret=interpret_mode(),
             has_dense=dpost.has_dense)
 
 
@@ -257,8 +255,7 @@ def fused_topk_scores(dpost, dpack, sq: StagedQuery, *, k: int, m: int,
     """(scores f32[Gq, k], ids i32[Gq, k]) device top-k over the fused
     score matrix — same pipeline, ``lax.top_k`` head (which ranks equal
     scores lowest-id-first, the dense (-score, id) tie rule)."""
-    from repro.kernels import postings_merge
-    from repro.kernels.ops import _on_tpu
+    from repro.kernels import interpret_mode, postings_merge
 
     _note_call(_sig("topk", dpost, sq, m=m, backend=backend, extra=(k,)))
     with _quiet():
@@ -267,7 +264,7 @@ def fused_topk_scores(dpost, dpack, sq: StagedQuery, *, k: int, m: int,
             dpost.off, dpost.payload,
             dpack.values, dpack.thresh, dpack.buf, sq.blob,
             k=k, gq=sq.gq, cq=sq.cq, w=sq.w,
-            m=m, backend=backend, interpret=not _on_tpu(),
+            m=m, backend=backend, interpret=interpret_mode(),
             has_dense=dpost.has_dense)
 
 

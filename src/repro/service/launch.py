@@ -17,7 +17,7 @@ import time
 
 import numpy as np
 
-from repro import api
+from repro import api, runtime
 from repro.launch.mesh import make_mesh
 from repro.data import datasets
 from repro.data.synth import make_query_workload
@@ -197,7 +197,8 @@ def smoke_rounds(app: ServiceApp, args) -> None:
               f"mean batch {stats.mean_batch:.1f})")
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
+    """The launcher's options (``argv=None`` reads the command line)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--mesh", default="1x1")
     ap.add_argument("--dataset", default="NETFLIX")
@@ -211,8 +212,15 @@ def main(argv=None):
                     help="run N smoke rounds and exit (0 = serve forever)")
     ap.add_argument("--topk", type=int, default=10)
     add_service_args(ap)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def main(argv=None):
+    args = parse_args(argv)
+    cache = runtime.use_compile_cache()
+    dev = runtime.device_summary()
+    print(f"[service] devices: platform={dev['platform']} "
+          f"kind={dev['kind']} count={dev['count']} (compile cache {cache})")
     app = build_service(args)
     if args.rounds > 0:
         smoke_rounds(app, args)

@@ -560,7 +560,10 @@ class AsyncSketchServer:
         self._stop = True
         with self._cv:
             self._cv.notify_all()
-        self._thread.join(timeout=10.0)
+        # No timeout: the worker is the only mutator, so the drain below
+        # must not overlap a flush it is still running, and the process
+        # must not exit while that flush is inside a device call.
+        self._thread.join()
         self._thread = None
         self.drain()
 

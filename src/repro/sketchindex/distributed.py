@@ -35,6 +35,13 @@ from repro.obs.trace import stage as obs_stage
 from repro.parallel.sharding import logical_to_spec
 
 
+#: Candidates per device scoring call of the mesh's pruned top-k. A call
+#: dispatches to every device and syncs with the host, so the host
+#: loop's small default chunk would make thousands of calls per batch at
+#: 480k records; scoring extra candidates never changes the answer.
+DEVICE_TOPK_CHUNK = 4096
+
+
 @dataclasses.dataclass
 class DeviceIndex:
     """Sharded PackedSketches + the metadata needed to sketch queries."""
@@ -113,8 +120,27 @@ def _scores_jnp(values, lengths, thresh, buf, q_values, q_thresh, q_buf, q_sizes
     return jax.vmap(one_query)(q_values, q_thresh, q_buf, q_sizes).T
 
 
-@functools.partial(jax.jit, static_argnames=("backend",))
-def _score_batch_jit(didx: DeviceIndex, q: PackedSketches, backend: str):
+def _scores_pallas(mesh: Mesh, values, thresh, buf, q_values, q_thresh,
+                   q_buf, q_sizes):
+    """The Pallas dense scorer, one kernel per record shard: a kernel is
+    opaque to the partitioner, so ``shard_map`` runs it on each device's
+    rows with the queries replicated."""
+    from repro.kernels import interpret_mode
+    from repro.kernels.ops import score_index
+
+    rows = logical_to_spec(("records",), mesh)[0]
+    interpret = interpret_mode(mesh.devices.flat[0].platform)
+    return compat.shard_map(
+        functools.partial(score_index, interpret=interpret), mesh=mesh,
+        in_specs=(P(rows, None), P(rows), P(rows, None),
+                  P(), P(), P(), P()),
+        out_specs=P(rows, None),
+    )(values, thresh, buf, q_values, q_thresh, q_buf, q_sizes)
+
+
+@functools.partial(jax.jit, static_argnames=("backend", "mesh"))
+def _score_batch_jit(didx: DeviceIndex, q: PackedSketches, backend: str,
+                     mesh: Mesh):
     qv = jnp.asarray(q.values, jnp.uint32)
     qt = jnp.asarray(q.thresh, jnp.uint32)
     qb = jnp.asarray(q.buf, jnp.uint32)
@@ -122,11 +148,48 @@ def _score_batch_jit(didx: DeviceIndex, q: PackedSketches, backend: str):
     if qb.shape[1] != didx.buf.shape[1]:
         qb = jnp.pad(qb, ((0, 0), (0, didx.buf.shape[1] - qb.shape[1])))
     if backend == "pallas":
-        from repro.kernels.ops import score_index
-        return score_index(didx.values, didx.thresh, didx.buf,
-                           qv, qt, qb, qs)
+        return _scores_pallas(mesh, didx.values, didx.thresh, didx.buf,
+                              qv, qt, qb, qs)
     return _scores_jnp(didx.values, didx.lengths, didx.thresh, didx.buf,
                        qv, qt, qb, qs)
+
+
+def _pairs_local(backend, interpret, x_rows, x_thresh, x_buf, q_values,
+                 q_thresh, q_buf, q_sizes, cand_rec, cand_q):
+    from repro.kernels import gather_score
+
+    if backend == "pallas":
+        return gather_score._gather_score_pallas(
+            x_rows, x_thresh, x_buf, q_values, q_thresh, q_buf, q_sizes,
+            cand_rec, cand_q, interpret=interpret)
+    return gather_score._gather_score_jnp(
+        x_rows, x_thresh, x_buf, q_values, q_thresh, q_buf, q_sizes,
+        cand_rec, cand_q)
+
+
+@functools.partial(jax.jit, static_argnames=("backend", "mesh"))
+def _score_pairs_jit(x_rows, x_thresh, x_buf, q: PackedSketches,
+                     cand_rec, cand_q, backend: str, mesh: Mesh):
+    """Pair scores f32[n·P] for candidates already split by owner:
+    ``cand_rec``/``cand_q`` i32[n·P] hold P pairs per record shard, with
+    record ids local to that shard. Each device gathers its own rows —
+    no pair is scored twice and no row crosses a chip."""
+    from repro.kernels import interpret_mode
+
+    rows = logical_to_spec(("records",), mesh)[0]
+    qb = jnp.asarray(q.buf, jnp.uint32)
+    if qb.shape[1] != x_buf.shape[1]:
+        qb = jnp.pad(qb, ((0, 0), (0, x_buf.shape[1] - qb.shape[1])))
+    return compat.shard_map(
+        functools.partial(_pairs_local, backend,
+                          interpret_mode(mesh.devices.flat[0].platform)),
+        mesh=mesh,
+        in_specs=(P(rows, None), P(rows), P(rows, None),
+                  P(), P(), P(), P(), P(rows), P(rows)),
+        out_specs=P(rows),
+    )(x_rows, x_thresh, x_buf, jnp.asarray(q.values, jnp.uint32),
+      jnp.asarray(q.thresh, jnp.uint32), qb,
+      jnp.asarray(q.sizes, jnp.int32), cand_rec, cand_q)
 
 
 def score_batch(didx: DeviceIndex, q: PackedSketches,
@@ -151,7 +214,7 @@ def score_batch(didx: DeviceIndex, q: PackedSketches,
             thresh=np.asarray(q.thresh), buf=np.asarray(q.buf),
             sizes=np.asarray(q.sizes))
         return containment_matrix(qh, x, backend="numpy")
-    return _score_batch_jit(didx, q, backend)
+    return _score_batch_jit(didx, q, backend, didx.values.sharding.mesh)
 
 
 jax.tree_util.register_dataclass(
@@ -161,11 +224,13 @@ jax.tree_util.register_dataclass(
 )
 
 
+@functools.partial(jax.jit, static_argnames=("k", "mesh"))
 def distributed_topk(scores, k: int, mesh: Mesh):
     """Global top-k over the sharded record dim via shard_map.
 
     scores f32[Mp, Gq] (rows sharded) -> (vals f32[Gq, k], ids i32[Gq, k]).
     Per-shard top-k then one tiny all_gather of (n_dev · k) candidates.
+    One compiled program per (shape, k, mesh).
     """
     row_axes = logical_to_spec(("records",), mesh)[0]
     n_shards = int(np.prod([mesh.shape[a] for a in (
@@ -232,6 +297,7 @@ class ShardedIndex:
         self.budget = budget if budget is not None else getattr(
             index, "budget", None)
         self.didx = to_device_index(core, mesh)
+        self._lane_rows = None
         self.last_plan = None
         # Explain/observability bookkeeping from the most recent planned
         # batch: per-query CandidateSets (pruned path only) and the
@@ -260,6 +326,55 @@ class ShardedIndex:
         the union reports global record ids either way.
         """
         return self.host.sketches.shard_postings(self.mesh.devices.size)
+
+    def _score_pairs(self, qp, cand_rec, cand_q) -> np.ndarray:
+        """f32[P] pair scores for the host merge's candidates. A device
+        backend scores against the resident sharded columns: the pairs
+        are grouped by the shard that holds their record, each device
+        scores only its group, and the scores return in input order."""
+        from repro.kernels import gather_score
+
+        if self.backend == "numpy":
+            return gather_score.score_pairs(self.host.sketches, qp, cand_rec,
+                                            cand_q, backend="numpy")
+        rec = np.asarray(cand_rec, np.int64)
+        if len(rec) == 0:
+            return np.zeros(0, np.float32)
+        n = self.mesh.devices.size
+        shard = self.didx.padded_records // n
+        owner = rec // shard
+        order = np.argsort(owner, kind="stable")
+        counts = np.bincount(owner, minlength=n)
+        # At least one kernel grid step per shard: top-k's chunks then
+        # share a few compiled widths instead of one per power of two.
+        width = gather_score._pad_pow2(int(counts.max()), gather_score.STEP)
+        # Slot of each (owner-sorted) pair in its shard's group of
+        # ``width``; the rest score (local row 0, query 0), never read.
+        rank = np.arange(len(rec)) - np.repeat(np.cumsum(counts) - counts,
+                                               counts)
+        slot = owner[order] * width + rank
+        local_rec = np.zeros(n * width, np.int32)
+        local_q = np.zeros(n * width, np.int32)
+        local_rec[slot] = rec[order] - owner[order] * shard
+        local_q[slot] = np.asarray(cand_q, np.int32)[order]
+        out = np.asarray(_score_pairs_jit(
+            self._pair_rows(), self.didx.thresh, self.didx.buf, qp,
+            local_rec, local_q, self.backend, self.mesh))
+        scores = np.empty(len(rec), np.float32)
+        scores[order] = out[slot]
+        return scores
+
+    def _pair_rows(self):
+        """The record rows the pair scorer reads: the Pallas kernel's
+        128-lane layout, built once per placement, or the columns."""
+        if self.backend != "pallas":
+            return self.didx.values
+        if self._lane_rows is None:
+            from repro.kernels.gather_score import lane_rows
+
+            self._lane_rows = jax.device_put(
+                lane_rows(self.didx.values), self.didx.values.sharding)
+        return self._lane_rows
 
     def _device_route(self) -> bool:
         """True when the fused all-device pipeline can serve this index:
@@ -316,15 +431,9 @@ class ShardedIndex:
                 plan=decision, backend=self.backend)
             return ids, qp
 
-        from repro.kernels import gather_score
-
-        def score_fn(cand_rec, cand_q):
-            return gather_score.score_pairs(
-                s, qp, cand_rec, cand_q, backend=self.backend)
-
         ids, cands = planner.pruned_batch(
-            posts, hash_rows, bit_rows, sizes, thresholds, score_fn,
-            row_offsets=offs)
+            posts, hash_rows, bit_rows, sizes, thresholds,
+            functools.partial(self._score_pairs, qp), row_offsets=offs)
         self.last_candidates = cands
         return ids, qp
 
@@ -464,7 +573,6 @@ class ShardedIndex:
         """Planner-aware top-k for a whole batch over ONE query pack
         (``qp`` reuses a pack the caller already sketched)."""
         from repro import planner
-        from repro.kernels import gather_score
         from repro.planner.plan import unpack_query_rows
 
         if qp is None:
@@ -480,14 +588,14 @@ class ShardedIndex:
         out = []
         for g in range(len(queries)):
             def score_fn(cand_rec, _cand_q, g=g):
-                return gather_score.score_pairs(
-                    s, qp, cand_rec,
-                    np.full(len(cand_rec), g, np.int32),
-                    backend=self.backend)
+                return self._score_pairs(
+                    qp, cand_rec, np.full(len(cand_rec), g, np.int32))
 
             out.append(planner.pruned_topk(
                 posts, hash_rows[g], bit_rows[g], int(sizes[g]), k,
-                score_fn, s.num_records, row_offsets=offs))
+                score_fn, s.num_records, row_offsets=offs,
+                chunk=None if self.backend == "numpy" else
+                DEVICE_TOPK_CHUNK))
         return out
 
     def topk(self, q_ids, k: int, *, plan: str = "auto"):
@@ -522,6 +630,7 @@ class ShardedIndex:
         self.host = wrapper.core
         self.stats = wrapper.stats
         self.didx = to_device_index(self.host, self.mesh)
+        self._lane_rows = None
         return self
 
     def save(self, path: str) -> None:

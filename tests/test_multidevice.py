@@ -46,6 +46,23 @@ for j, q in enumerate(queries):
                                rtol=1e-5, atol=1e-5)
 print("score-ok")
 
+# --- pair scorer over the sharded columns == over the host pack ---
+from repro.kernels.gather_score import score_pairs
+from repro.sketchindex import ShardedIndex
+# Skewed owners: most pairs on the first shard, none on some.
+rec = np.concatenate([rng.integers(0, 12, 250),
+                      rng.integers(0, idx.num_records, 50)]).astype(np.int32)
+qi = rng.integers(0, len(queries), 300).astype(np.int32)
+for backend in ("jnp", "pallas"):
+    sh = ShardedIndex(idx, mesh, backend=backend)
+    np.testing.assert_array_equal(
+        sh._score_pairs(qp, rec, qi),
+        score_pairs(idx.sketches, qp, rec, qi, backend=backend))
+    np.testing.assert_array_equal(
+        sh._score_pairs(qp, rec[:5], qi[:5]),
+        score_pairs(idx.sketches, qp, rec[:5], qi[:5], backend=backend))
+print("pairs-ok")
+
 # --- distributed τ (psum histogram) == single-device histogram ---
 h = rng.integers(0, 2**32, size=16384).astype(np.uint32)
 t1 = int(histogram_tau(jnp.asarray(h), 900))
@@ -63,5 +80,5 @@ def test_shard_map_paths_on_8_devices():
     r = subprocess.run([sys.executable, "-c", SCRIPT], capture_output=True,
                        text=True, timeout=420, env=env)
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
-    for marker in ("topk-ok", "score-ok", "tau-ok"):
+    for marker in ("topk-ok", "score-ok", "pairs-ok", "tau-ok"):
         assert marker in r.stdout, (marker, r.stdout[-500:])

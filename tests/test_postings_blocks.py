@@ -237,9 +237,9 @@ def test_block_decode_pallas_kernel_matches_jnp():
     off = jnp.asarray(st.off[sparse], jnp.int32)
     a = np.asarray(pm._decode_sparse_jnp(first, off, jnp.asarray(bw),
                                          jnp.asarray(cnt), pay))
-    b = np.asarray(pm._decode_sparse_pallas(first, off, jnp.asarray(bw),
-                                            jnp.asarray(cnt), pay,
-                                            interpret=True))
+    b = np.asarray(pm._decode_sparse_pallas(
+        first, off, jnp.asarray(bw), jnp.asarray(cnt),
+        pm._decode_payload_rows(jnp.asarray(st.payload)), interpret=True))
     lanes = np.arange(P.BLOCK)[None, :]
     valid = lanes < cnt[:, None]
     np.testing.assert_array_equal(a[valid], b[valid])
