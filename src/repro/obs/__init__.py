@@ -9,8 +9,8 @@ Three pieces, all stdlib+numpy only:
 * :mod:`repro.obs.explain` — per-query plan explain built from
   ``QueryPlan`` / ``CandidateSet`` internals.
 * :mod:`repro.obs.profile` — per-stage latency histograms
-  (:class:`StageProfiler`), cost-model drift accounting
-  (:class:`CostDrift`), and the gated ``jax.profiler`` wrapper.
+  (:class:`StageProfiler`) and cost-model drift accounting
+  (:class:`CostDrift`).
 
 Off-by-default-cheap: with no context attached, ``stage()`` is a shared
 no-op; the serving bench gates end-to-end tracing overhead at ≤5% QPS.
@@ -24,16 +24,14 @@ from repro.obs.trace import (  # noqa: F401
     Tracer,
     attach,
     chrome_events,
+    contained,
     current_profiler,
     current_trace,
+    faults,
     stage,
 )
 from repro.obs.explain import build_explain, cost_fields  # noqa: F401
-from repro.obs.profile import (  # noqa: F401
-    CostDrift,
-    StageProfiler,
-    device_profile,
-)
+from repro.obs.profile import CostDrift, StageProfiler  # noqa: F401
 
 
 def device_pipeline_stats() -> dict:
@@ -50,7 +48,8 @@ def device_pipeline_stats() -> dict:
 __all__ = [
     "Tracer", "NullTracer", "NULL_TRACER", "Trace", "Span",
     "attach", "stage", "current_trace", "current_profiler", "chrome_events",
+    "contained", "faults",
     "build_explain", "cost_fields",
-    "StageProfiler", "CostDrift", "device_profile",
+    "StageProfiler", "CostDrift",
     "device_pipeline_stats",
 ]

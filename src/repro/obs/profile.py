@@ -1,5 +1,5 @@
-"""Stage-level profiling: per-stage latency histograms, cost-model drift
-accounting, and an optional ``jax.profiler`` session wrapper.
+"""Stage-level profiling: per-stage latency histograms and cost-model
+drift accounting.
 
 Device timing caveat (why stages are *host-side spans at sync
 boundaries*): the pruned query path fuses decode+score inside one jitted
@@ -15,14 +15,12 @@ computation, so the only honest host-visible seams are data staging
 
 from __future__ import annotations
 
-import contextlib
 import math
-import os
 import threading
 
 from repro.serving.histogram import Histogram
 
-__all__ = ["StageProfiler", "CostDrift", "device_profile"]
+__all__ = ["StageProfiler", "CostDrift"]
 
 
 class StageProfiler:
@@ -144,26 +142,3 @@ class CostDrift:
         r = self.last_ratio
         return r if math.isfinite(r) else 0.0
 
-
-@contextlib.contextmanager
-def device_profile(logdir: str | None = None):
-    """Optional ``jax.profiler`` trace session around a block.
-
-    Gated: does nothing unless ``logdir`` is given or the
-    ``REPRO_JAX_PROFILE`` env var names a directory. The resulting
-    TensorBoard/Perfetto trace carries real device timelines; this
-    wrapper exists so benches/serving can opt in with one flag without
-    importing jax on the default path.
-    """
-    if logdir is None:
-        logdir = os.environ.get("REPRO_JAX_PROFILE", "")
-    if not logdir:
-        yield None
-        return
-    import jax
-
-    jax.profiler.start_trace(logdir)
-    try:
-        yield logdir
-    finally:
-        jax.profiler.stop_trace()

@@ -21,12 +21,20 @@ profiler): ...`` — and deep layers call ``with stage("planner.probe"):``
 which records into whatever is attached. When nothing is attached,
 ``stage()`` returns a shared no-op context manager: the disabled cost is
 one thread-local attribute read and a truthiness check, measured ≤5%
-end-to-end by the serving bench's tracing gate.
+end-to-end by the serving bench's tracing gate. An attached stage also
+holds a ``jax.profiler.TraceAnnotation`` of its name, so the stage shows
+on the device trace's clock whenever a profiler session runs.
+
+Observability must never cost an answer: a raise inside a stage's own
+bookkeeping, or inside code run under :func:`contained`, is counted
+(:func:`faults`) and dropped.
 """
 
 from __future__ import annotations
 
+import contextlib
 import itertools
+import logging
 import threading
 import time
 from collections import deque
@@ -35,8 +43,10 @@ from typing import Callable
 __all__ = [
     "Span", "Trace", "Tracer", "NULL_TRACER",
     "attach", "current_trace", "current_profiler", "stage",
-    "chrome_events",
+    "chrome_events", "contained", "faults",
 ]
+
+log = logging.getLogger(__name__)
 
 
 def _jsonable(v):
@@ -349,12 +359,56 @@ def current_profiler():
     return st[-1][1] if st else None
 
 
+# Process-wide count of contained observability faults: stages run on
+# every thread and inside any library call, so the count cannot belong
+# to one server object. Read it as a delta where that matters.
+_faults = 0
+_faults_lock = threading.Lock()
+
+
+def faults() -> int:
+    """Observability faults contained so far in this process."""
+    return _faults
+
+
+@contextlib.contextmanager
+def contained():
+    """Run observability code whose raise must not reach the caller: the
+    raise is logged at debug level, counted in :func:`faults`, and
+    dropped."""
+    global _faults
+    try:
+        yield
+    except Exception:
+        log.debug("observability fault contained", exc_info=True)
+        with _faults_lock:
+            _faults += 1
+
+
+_trace_annotation = None
+
+
+def _annotation(name: str):
+    """An un-entered ``jax.profiler.TraceAnnotation(name)``; jax is
+    imported on first use so this module stays importable without it."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = contextlib.nullcontext
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation(name)
+
+
 class _Stage:
     """Times one stage into the attached trace span AND the attached
-    profiler histogram. ``sync(x)`` blocks on a device value so the
-    stage's wall time covers its device work (identity off-context)."""
+    profiler histogram, and holds a profiler annotation of its name
+    (entered and exited on the calling thread; attributes stay in the
+    trace). ``sync(x)`` blocks on a device value so the stage's wall
+    time covers its device work (identity off-context)."""
 
-    __slots__ = ("name", "attrs", "_trace", "_prof", "_span", "_t0")
+    __slots__ = ("name", "attrs", "_trace", "_prof", "_span", "_t0", "_ann")
 
     def __init__(self, trace, prof, name, attrs):
         self.name = name
@@ -362,6 +416,7 @@ class _Stage:
         self._trace = trace
         self._prof = prof
         self._span = None
+        self._ann = None
 
     def set(self, **attrs):
         if self._span is not None:
@@ -378,22 +433,32 @@ class _Stage:
         return x
 
     def __enter__(self):
-        if self._trace is not None:
-            self._span = self._trace.span(self.name, **self.attrs).span
         self._t0 = (self._trace.tracer.clock() if self._trace is not None
                     else time.perf_counter())
+        with contained():
+            if self._trace is not None:
+                self._span = self._trace.span(self.name, **self.attrs).span
+        with contained():
+            ann = _annotation(self.name)
+            ann.__enter__()
+            self._ann = ann
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        if self._trace is not None:
-            now = self._trace.tracer.clock()
-            if exc_type is not None and self._span is not None:
-                self._span.attrs["error"] = exc_type.__name__
-            self._trace._close(self._span) if self._span is not None else None
-        else:
-            now = time.perf_counter()
-        if self._prof is not None:
-            self._prof.observe(self.name, max(now - self._t0, 0.0))
+        if self._ann is not None:
+            with contained():
+                self._ann.__exit__(exc_type, exc, tb)
+        with contained():
+            if self._trace is not None:
+                now = self._trace.tracer.clock()
+                if self._span is not None:
+                    if exc_type is not None:
+                        self._span.attrs["error"] = exc_type.__name__
+                    self._trace._close(self._span)
+            else:
+                now = time.perf_counter()
+            if self._prof is not None:
+                self._prof.observe(self.name, max(now - self._t0, 0.0))
         return False
 
 

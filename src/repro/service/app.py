@@ -63,6 +63,7 @@ from urllib.parse import parse_qs
 
 import numpy as np
 
+from repro import obs
 from repro.service.metrics import Metrics
 from repro.service.middleware import (AuthToken, TenantBuckets, TokenBucket,
                                       tenant_id)
@@ -255,6 +256,10 @@ class ServiceApp:
                          lambda: srv.deduped_total,
                          help="Ingest chunks skipped by the idempotency "
                               "window (safe client retries)")
+        m.set_counter_fn("service_obs_faults_total", obs.faults,
+                         help="Observability faults (a raise in a span, "
+                              "trace or histogram) contained before they "
+                              "reached a request, in this process")
         # Durability gauges — only when the server mounts a data dir.
         d = srv.durability
         if d is not None:
@@ -360,7 +365,6 @@ class ServiceApp:
         # stats dict is plain ints, no jax import on the scrape path.
         def _pipe(key):
             def fn():
-                from repro import obs
                 return obs.device_pipeline_stats()[key]
             return fn
 
@@ -398,9 +402,19 @@ class ServiceApp:
 
     def handle(self, method: str, path: str, headers, rfile) -> Response:
         """One request → one response. ``headers`` is mapping-like;
-        ``rfile`` a binary stream positioned at the body."""
+        ``rfile`` a binary stream positioned at the body. A read is
+        timed as the ``http.request`` stage into the server's profiler
+        (``http.wait`` is its wait for the flush)."""
         raw, _, query = path.partition("?")
         endpoint = raw.rstrip("/") or "/"
+        if endpoint not in ("/topk", "/query"):
+            return self._handle(method, endpoint, query, headers, rfile)
+        with obs.attach(None, self.server.profiler), \
+                obs.stage("http.request"):
+            return self._handle(method, endpoint, query, headers, rfile)
+
+    def _handle(self, method: str, endpoint: str, query: str, headers,
+                rfile) -> Response:
         t0 = self.clock()
         body = _Body(rfile, headers)
         try:
@@ -413,15 +427,16 @@ class ServiceApp:
         # request. An undecodable body forces a fresh connection instead.
         if not body.drain():
             resp.headers["Connection"] = "close"
-        self.metrics.inc(
-            "service_requests_total",
-            {"endpoint": endpoint.lstrip("/") or "root",
-             "status": str(resp.status)},
-            help="Requests by endpoint and HTTP status")
-        self.metrics.observe(
-            "service_request_latency_seconds", self.clock() - t0,
-            {"endpoint": endpoint.lstrip("/") or "root"},
-            help="End-to-end in-service latency")
+        with obs.contained():
+            self.metrics.inc(
+                "service_requests_total",
+                {"endpoint": endpoint.lstrip("/") or "root",
+                 "status": str(resp.status)},
+                help="Requests by endpoint and HTTP status")
+            self.metrics.observe(
+                "service_request_latency_seconds", self.clock() - t0,
+                {"endpoint": endpoint.lstrip("/") or "root"},
+                help="End-to-end in-service latency")
         return resp
 
     def _route(self, method: str, endpoint: str, headers,
@@ -530,7 +545,8 @@ class ServiceApp:
             np.asarray(body["q"], np.int64),
             threshold=float(body.get("threshold", 0.5)),
             deadline=self._deadline_s(body), explain=explain)
-        res = self.server.result(p, timeout=self.result_timeout)
+        with obs.stage("http.wait"):
+            res = self.server.result(p, timeout=self.result_timeout)
         out = {"rid": p.rid,
                "hits": np.asarray(res["hits"]).tolist(),
                "expired": p.expired}
@@ -542,7 +558,8 @@ class ServiceApp:
         p = self.server.submit_topk(
             np.asarray(body["q"], np.int64), k=int(body.get("k", 10)),
             deadline=self._deadline_s(body))
-        res = self.server.result(p, timeout=self.result_timeout)
+        with obs.stage("http.wait"):
+            res = self.server.result(p, timeout=self.result_timeout)
         return Response(200, {
             "rid": p.rid,
             "ids": np.asarray(res["topk_ids"]).tolist(),

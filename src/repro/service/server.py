@@ -33,6 +33,7 @@ from typing import Callable
 
 import numpy as np
 
+from repro import obs
 from repro.serving.batcher import BatchStats, execute_batch
 from repro.service.wal import IdempotencyCache, ReadOnly
 
@@ -92,7 +93,6 @@ class AsyncSketchServer:
                  slow_threshold: float | None = 1.0,
                  slow_log_size: int = 128,
                  durability=None, idem_window: int = 1024):
-        from repro.obs import CostDrift, StageProfiler
         from repro.planner import normalize_plan
 
         self.index = index
@@ -112,8 +112,8 @@ class AsyncSketchServer:
         # over the batch). ``slow_threshold`` seconds of total latency
         # (admission → answered) lands a request in the bounded slow log.
         self.tracer = tracer
-        self.profiler = StageProfiler() if profile else None
-        self.cost_drift = CostDrift()
+        self.profiler = obs.StageProfiler() if profile else None
+        self.cost_drift = obs.CostDrift()
         self.slow_threshold = slow_threshold
         self.slow_queries = 0
         self.slow_log: deque[dict] = deque(maxlen=int(slow_log_size))
@@ -262,31 +262,43 @@ class AsyncSketchServer:
         """One flush-loop iteration: gather → execute → complete events.
         Returns the number of requests answered. ``block`` waits (real
         time) for a flushable batch; ``force`` flushes a partial batch
-        immediately (drain/test hook)."""
+        immediately (drain/test hook). The server's profiler is attached
+        for the whole iteration, so the wait shows as ``flush.gather``."""
+        with obs.attach(None, self.profiler):
+            with obs.stage("flush.gather"):
+                batch, hint = self._next_batch(block, timeout, force)
+            if batch is None:
+                return 0
+            # Lock released: submitters keep filling the queue while the
+            # batch executes on device — the overlap this server exists
+            # for.
+            if hint == "ingest":
+                self._execute_ingest(batch)
+            else:
+                self._execute_serve(batch, reason=hint)
+            return len(batch)
+
+    def _next_batch(self, block: bool, timeout: float | None, force: bool):
+        """(batch, reason) popped under the lock, or (None, None) when
+        nothing is flushable (after waiting up to ``timeout`` if
+        ``block``)."""
         deadline = (time.monotonic() + timeout) if (block and timeout) else None
         with self._cv:
             while True:
                 batch, hint = self._gather(self.clock(), force)
                 if batch is not None:
-                    break
+                    return batch, hint
                 if not block:
-                    return 0
+                    return None, None
                 wait = hint if hint is not None else 0.05
                 if deadline is not None:
                     remaining = deadline - time.monotonic()
                     if remaining <= 0:
-                        return 0
+                        return None, None
                     wait = min(wait, remaining)
                 if not self._cv.wait(timeout=wait) and self._stop \
                         and not self._queue:
-                    return 0
-        # Lock released: submitters keep filling the queue while the
-        # batch executes on device — the overlap this server exists for.
-        if hint == "ingest":
-            self._execute_ingest(batch)
-        else:
-            self._execute_serve(batch, reason=hint)
-        return len(batch)
+                    return None, None
 
     def drain(self):
         """Flush until the queue is empty (shutdown / test barrier)."""
@@ -334,19 +346,18 @@ class AsyncSketchServer:
             })
 
     def _execute_serve(self, batch: list[Pending], reason: str):
-        from repro import obs
-
         now = self.clock()
         fresh = [p for p in batch if not p.past_deadline(now)]
         late = [p for p in batch if p.past_deadline(now)]
         ftrace = None
-        if self.tracer is not None:
-            ftrace = self.tracer.begin("flush", reason=reason,
-                                       batch=len(batch),
-                                       rids=[p.rid for p in batch])
-            # Batch assembly: oldest admission → this flush starting.
-            ftrace.add_span("assemble", min(p.arrival for p in batch), now,
-                            batch=len(batch))
+        with obs.contained():
+            if self.tracer is not None:
+                ftrace = self.tracer.begin("flush", reason=reason,
+                                           batch=len(batch),
+                                           rids=[p.rid for p in batch])
+                # Batch assembly: oldest admission → this flush starting.
+                ftrace.add_span("assemble", min(p.arrival for p in batch),
+                                now, batch=len(batch))
         try:
             # Deadline-expired requests take the dense fallback: one
             # predictable sweep, no postings-probe variance, answered
@@ -371,20 +382,15 @@ class AsyncSketchServer:
                                             stats=self.stats,
                                             clock=self.clock,
                                             explain=explain)
-                t1 = self.clock()
-                self._record_drift(t1 - t0)
-                for p in sub:
-                    res = out[p.rid]
-                    if p.kind == "topk":
-                        p.result = {
-                            "topk_ids": res["topk_ids"][: p.k],
-                            "topk_scores": res["topk_scores"][: p.k]}
-                    else:
-                        p.result = {"hits": res["hits"]}
-                    if p.explain and "explain" in res:
-                        p.result["explain"] = res["explain"]
-                    p.expired = why == "expired"
-                    self._finish_request(p, why, plan, now, t0, t1, len(sub))
+                    t1 = self.clock()
+                    with obs.stage("flush.answer", batch=len(sub)):
+                        self._answer(sub, out, why)
+                        with obs.contained():
+                            self._record_drift(t1 - t0)
+                        for p in sub:
+                            with obs.contained():
+                                self._finish_request(p, why, plan, now, t0,
+                                                     t1, len(sub))
                 if why == "expired":
                     self.expired_served += len(sub)
             self._complete(batch)
@@ -392,7 +398,26 @@ class AsyncSketchServer:
             self._complete(batch, err=e)
         finally:
             if ftrace is not None:
-                ftrace.end()
+                with obs.contained():
+                    ftrace.end()
+
+    @staticmethod
+    def _answer(sub: list[Pending], out: dict, why: str) -> None:
+        """Set every request's result, then release every waiter — before
+        any per-request observability, which therefore cannot cost an
+        answer."""
+        for p in sub:
+            res = out[p.rid]
+            if p.kind == "topk":
+                p.result = {"topk_ids": res["topk_ids"][: p.k],
+                            "topk_scores": res["topk_scores"][: p.k]}
+            else:
+                p.result = {"hits": res["hits"]}
+            if p.explain and "explain" in res:
+                p.result["explain"] = res["explain"]
+            p.expired = why == "expired"
+        for p in sub:
+            p.done.set()
 
     def _enter_read_only(self, err: OSError) -> None:
         """Sticky degrade: the data dir failed a write (ENOSPC, EROFS,
@@ -482,23 +507,24 @@ class AsyncSketchServer:
                     self.index.insert(p.records)
                 else:
                     self.index.insert(p.records, epoch=p.epoch)
-                # Host insert latency stays out of flush_latency_hist —
-                # that histogram is the device-flush basis for the 429
-                # Retry-After hint.
                 t1 = self.clock()
-                self.stats.ingest_latency_hist.observe(t1 - t0)
                 self.records_ingested += len(p.records)
                 p.result = {"ingested": len(p.records)}
                 if p.idem is not None:
                     self.idem.put(p.idem, {"ingested": len(p.records)})
-                if self.profiler is not None:
-                    self.profiler.observe("request.ingest",
-                                          max(t1 - p.arrival, 0.0))
-                if p.trace is not None:
-                    p.trace.add_span("queue_wait", p.arrival, now)
-                    p.trace.add_span("insert", t0, t1,
-                                     records=len(p.records))
-                    p.trace.end(kind="ingest")
+                # Host insert latency stays out of flush_latency_hist —
+                # that histogram is the device-flush basis for the 429
+                # Retry-After hint.
+                with obs.contained():
+                    self.stats.ingest_latency_hist.observe(t1 - t0)
+                    if self.profiler is not None:
+                        self.profiler.observe("request.ingest",
+                                              max(t1 - p.arrival, 0.0))
+                    if p.trace is not None:
+                        p.trace.add_span("queue_wait", p.arrival, now)
+                        p.trace.add_span("insert", t0, t1,
+                                         records=len(p.records))
+                        p.trace.end(kind="ingest")
             except Exception as e:
                 p.error = e
             p.done.set()
@@ -517,8 +543,9 @@ class AsyncSketchServer:
         except Exception as e:
             p.error = e
         if self.profiler is not None:
-            self.profiler.observe("request.snapshot",
-                                  max(self.clock() - t0, 0.0))
+            with obs.contained():
+                self.profiler.observe("request.snapshot",
+                                      max(self.clock() - t0, 0.0))
         p.done.set()
 
     # -- lifecycle ---------------------------------------------------------

@@ -400,6 +400,88 @@ def test_http_streaming_ingest_chunks():
         cli.close()
 
 
+class _FaultyAnnotation:
+    """A profiler annotation that raises on enter or, every other time,
+    on exit; ``raised`` counts both."""
+
+    raised = 0
+    made = 0
+    lock = threading.Lock()
+
+    def __init__(self, name):
+        with self.lock:
+            type(self).made += 1
+            self.on_enter = type(self).made % 2 == 1
+
+    def _raise(self):
+        with self.lock:
+            type(self).raised += 1
+        raise RuntimeError("annotation fault")
+
+    def __enter__(self):
+        if self.on_enter:
+            self._raise()
+        return self
+
+    def __exit__(self, *exc):
+        self._raise()
+
+
+def test_observability_faults_never_cost_an_answer(monkeypatch):
+    """Every profiler annotation and every request's bookkeeping raises:
+    a full flush of 16 top-k reads over HTTP still answers all 16 with
+    200 and the direct index's ids, and ``service_obs_faults_total``
+    counts each raise."""
+    from repro import api, obs
+    from repro.data.synth import generate_dataset
+    from repro.launch.mesh import make_mesh
+    from repro.obs import trace as obs_trace
+    from repro.sketchindex import ShardedIndex
+
+    recs = generate_dataset(m=100, n_elems=3000, alpha_freq=1.1,
+                            alpha_size=2.0, seed=0)
+    index = api.get_engine("gbkmv").build(
+        recs, sum(len(r) for r in recs) // 5)
+    sharded = ShardedIndex(index, make_mesh((1, 1), ("data", "model")))
+    queries = recs[:16]
+    want = [sharded.topk(q, 5)[0] for q in queries]
+    srv = AsyncSketchServer(sharded, max_batch=16, max_wait=30.0,
+                            default_deadline=None)
+    app = ServiceApp(srv)
+    finish_raised = []
+
+    def finish(*a, **kw):
+        finish_raised.append(1)
+        raise RuntimeError("bookkeeping fault")
+
+    monkeypatch.setattr(obs_trace, "_annotation", _FaultyAnnotation)
+    monkeypatch.setattr(AsyncSketchServer, "_finish_request", finish)
+    before = obs.faults()
+    answers = [None] * len(queries)
+    with ServiceHandle(app) as h:
+        def read(i):
+            cli = ServiceClient(*h.address)
+            answers[i] = cli.request("POST", "/topk", body=json.dumps(
+                {"q": queries[i].tolist(), "k": 5}).encode())
+            cli.close()
+
+        threads = [threading.Thread(target=read, args=(i,))
+                   for i in range(len(queries))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+    for (status, raw, _), ids in zip(answers, want):
+        assert status == 200, raw
+        np.testing.assert_array_equal(json.loads(raw)["ids"], ids)
+    assert srv.stats.flushes_full == 1 and srv.stats.total_batch == 16
+    assert len(finish_raised) == 16
+    faults = parse_prometheus(app.metrics.render())["service_obs_faults_total"]
+    assert faults - before == obs.faults() - before \
+        == _FaultyAnnotation.raised + len(finish_raised)
+
+
 # -- live HTTP against the real index: bit-identical parity -----------------
 
 
@@ -469,3 +551,4 @@ def test_http_metrics_shape(live_service):
     assert pm["arena_sketch_nbytes"] > 0
     assert pm["service_queue_wait_seconds_count"] >= 1
     cli.close()
+
