@@ -393,10 +393,8 @@ def _annotation(name: str):
     imported on first use so this module stays importable without it."""
     global _trace_annotation
     if _trace_annotation is None:
-        try:
-            from jax.profiler import TraceAnnotation
-        except ImportError:
-            TraceAnnotation = contextlib.nullcontext
+        from jax.profiler import TraceAnnotation
+
         _trace_annotation = TraceAnnotation
     return _trace_annotation(name)
 
