@@ -41,6 +41,10 @@ def gkmv_pair_estimate(
       x_thresh:  uint32[m]
 
     Returns (d_hat f32[m], k i32[m], k_cap i32[m]).
+
+    U₍ₖ₎ is each row's masked max, not the value at index n − 1: sorted rows
+    keep their live values as a prefix, and on the TPU a per-row index lowers
+    to a gather.
     """
     q_values = jnp.asarray(q_values, dtype=jnp.uint32)
     x_values = jnp.asarray(x_values, dtype=jnp.uint32)
@@ -59,15 +63,11 @@ def gkmv_pair_estimate(
 
     k = nq + nx - k_cap                                         # |L_Q ∪ L_X| [m]
 
-    # U_(k): largest hash ≤ τ_pair in either row. Rows are ascending, so it
-    # is max(last-live-of-Q, last-live-of-X).
-    def last_live(vals, n):
-        idx = jnp.maximum(n - 1, 0)
-        v = jnp.take_along_axis(vals, idx[:, None].astype(jnp.int32), axis=-1)[:, 0]
-        return jnp.where(n > 0, v, jnp.uint32(0))
-
-    uq = last_live(jnp.broadcast_to(q_values[None, :], (x_values.shape[0],) + q_values.shape), nq)
-    ux = last_live(x_values, nx)
+    # U_(k): largest hash ≤ τ_pair in either row, 0 where neither has one.
+    zero = jnp.uint32(0)
+    uq = jnp.max(jnp.where(q_values[None, :] <= tau_pair[:, None],
+                           q_values[None, :], zero), axis=-1)   # [m]
+    ux = jnp.max(jnp.where(live, x_values, zero), axis=-1)      # [m]
     u = jnp.maximum(uq, ux)
     u_unit = (u.astype(jnp.float32) + 1.0) / TWO32
 

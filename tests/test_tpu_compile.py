@@ -6,7 +6,8 @@ does not fit VMEM or a primitive Mosaic cannot lower passes every
 interpret-mode test. These tests compile each kernel, and the fused query
 programs, for a v5e chip that is described but not attached, at the
 sizes of the NETFLIX deployment (480,000 records, sketch capacity 48,
-16 queries per batch). Nothing runs.
+16 queries per batch), and the dense sweep at those of REUTERS. Nothing
+runs.
 """
 
 import os
@@ -134,6 +135,22 @@ def test_fused_query_program_compiles(spec, backend, head):
     compiled, text = _compile(fn, *args)
     assert ("tpu_custom_call" in text) == (backend == "pallas")
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+def test_dense_sweep_compiles_without_gathers(spec):
+    """The served dense sweep at the REUTERS widths (833,081 records,
+    capacity 72, 16 queries): a per-row index read of U_(k) would lower
+    to a gather over every (query, record) pair."""
+    from repro.sketchindex.distributed import _scores_jnp
+
+    m, c, w = 833_081, 72, 1
+    _, text = _compile(_scores_jnp, spec((m, c), jnp.uint32),
+                       spec((m,), jnp.int32), spec((m,), jnp.uint32),
+                       spec((m, w), jnp.uint32), spec((GQ, c), jnp.uint32),
+                       spec((GQ,), jnp.uint32), spec((GQ, w), jnp.uint32),
+                       spec((GQ,), jnp.int32))
+    assert "fusion" in text
+    assert "gather(" not in text
 
 
 @pytest.fixture(scope="module")
