@@ -29,6 +29,7 @@ from repro.obs.trace import (  # noqa: F401
     current_trace,
     faults,
     stage,
+    tally,
 )
 from repro.obs.explain import build_explain, cost_fields  # noqa: F401
 from repro.obs.profile import CostDrift, StageProfiler  # noqa: F401
@@ -47,7 +48,8 @@ def device_pipeline_stats() -> dict:
 
 __all__ = [
     "Tracer", "NullTracer", "NULL_TRACER", "Trace", "Span",
-    "attach", "stage", "current_trace", "current_profiler", "chrome_events",
+    "attach", "stage", "tally", "current_trace", "current_profiler",
+    "chrome_events",
     "contained", "faults",
     "build_explain", "cost_fields",
     "StageProfiler", "CostDrift",

@@ -29,12 +29,15 @@ class StageProfiler:
     Thread-safe; designed to be attached alongside a trace via
     ``obs.attach(trace, profiler)`` so ``obs.stage(...)`` blocks feed it
     without plumbing. ``histograms()`` is the live view a Metrics
-    histogram-family provider samples at render time.
+    histogram-family provider samples at render time. Tallies
+    (``obs.tally``: a served batch's route, the blocks its probe saw)
+    share the table, so ``stages()`` carries them too.
     """
 
     def __init__(self, bounds=None):
         self._bounds = bounds
         self._stages: dict[str, Histogram] = {}
+        self._tallies: set[str] = set()
         self._lock = threading.Lock()
 
     def observe(self, name: str, seconds: float) -> None:
@@ -46,22 +49,47 @@ class StageProfiler:
                     else Histogram())
         h.observe(seconds)
 
+    def tally(self, name: str, amount: float) -> None:
+        """Count one event with an amount (``obs.tally``): kept beside
+        the stages, so ``stages()`` carries it as (count, sum), but it
+        is no latency and stays out of ``histograms()``."""
+        if name not in self._tallies:
+            with self._lock:
+                self._tallies.add(name)
+        self.observe(name, amount)
+
     def histogram(self, name: str) -> Histogram | None:
         return self._stages.get(name)
 
     def histograms(self) -> dict[str, Histogram]:
-        """{prometheus labels string: Histogram} for a metrics provider."""
+        """{prometheus labels string: Histogram} for a metrics provider:
+        the stage latencies, tallies left out."""
         with self._lock:
-            return {f'stage="{k}"': h for k, h in self._stages.items()}
+            return {f'stage="{k}"': h for k, h in self._stages.items()
+                    if k not in self._tallies}
 
     def stages(self) -> dict[str, Histogram]:
+        """Every histogram by name, tallies included."""
         with self._lock:
             return dict(self._stages)
+
+    def tallies(self) -> dict[str, tuple[int, float]]:
+        """(events, summed amount) per tally name."""
+        with self._lock:
+            hs = [(n, self._stages.get(n)) for n in self._tallies]
+        out = {}
+        for name, h in hs:
+            if h is not None:
+                counts, total = h.snapshot()
+                out[name] = (int(counts.sum()), total)
+        return out
 
     def snapshot(self) -> dict[str, dict]:
         """Summary per stage: count / mean / p50 / p99 (seconds)."""
         out = {}
         for name, h in self.stages().items():
+            if name in self._tallies:
+                continue
             out[name] = {
                 "count": h.count,
                 "mean_s": h.mean,

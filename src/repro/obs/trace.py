@@ -42,7 +42,7 @@ from typing import Callable
 
 __all__ = [
     "Span", "Trace", "Tracer", "NULL_TRACER",
-    "attach", "current_trace", "current_profiler", "stage",
+    "attach", "current_trace", "current_profiler", "stage", "tally",
     "chrome_events", "contained", "faults",
 ]
 
@@ -473,3 +473,15 @@ def stage(name: str, **attrs):
         return _NULL_CTX
     trace, prof = st[-1]
     return _Stage(trace, prof, name, attrs)
+
+
+def tally(name: str, amount: float = 1.0) -> None:
+    """Count one event of ``name`` into the attached profiler: a
+    histogram whose count is the events and whose sum is ``amount``
+    over them (a route's batches and their queries, a probe's blocks).
+    A no-op with nothing attached; a raise is contained."""
+    st = getattr(_ctx, "stack", None)
+    if not st or st[-1][1] is None:
+        return
+    with contained():
+        st[-1][1].tally(name, amount)

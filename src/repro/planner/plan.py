@@ -192,21 +192,20 @@ def choose_plan(
         return QueryPlan("dense", 0.0, np.inf, 0,
                          "threshold <= 0: pruning unsound, forced dense")
     per, tb, td, bb = _probe(posts, q_hash_rows, q_bit_rows)
-    hits = int(per.sum())
-    est_dense = cost_model.dense_sweep_cost(m, capacity, gq)
-    est_pruned = cost_model.pruned_path_cost(hits, capacity, gq,
-                                             blocks=tb + bb)
-    blk = dict(blocks=tb + bb, tail_blocks=tb, tail_dense_blocks=td)
-    if plan == "dense":
-        return QueryPlan("dense", est_dense, est_pruned, hits, "forced",
-                         per, **blk)
-    if plan == "pruned":
-        return QueryPlan("pruned", est_dense, est_pruned, hits, "forced",
-                         per, **blk)
-    path = "pruned" if est_pruned < est_dense else "dense"
-    return QueryPlan(path, est_dense, est_pruned, hits,
-                     f"auto: dense≈{est_dense:.3g} vs pruned≈{est_pruned:.3g}",
-                     per, **blk)
+    # The decision from the probe's counts, apart from the probe itself.
+    with stage("planner.choose", queries=gq):
+        hits = int(per.sum())
+        est_dense = cost_model.dense_sweep_cost(m, capacity, gq)
+        est_pruned = cost_model.pruned_path_cost(hits, capacity, gq,
+                                                 blocks=tb + bb)
+        blk = dict(blocks=tb + bb, tail_blocks=tb, tail_dense_blocks=td)
+        if plan == "auto":
+            path = "pruned" if est_pruned < est_dense else "dense"
+            reason = f"auto: dense≈{est_dense:.3g} vs pruned≈{est_pruned:.3g}"
+        else:
+            path, reason = plan, "forced"
+        return QueryPlan(path, est_dense, est_pruned, hits, reason, per,
+                         **blk)
 
 
 def merged_candidates(

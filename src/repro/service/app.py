@@ -385,6 +385,36 @@ class ServiceApp:
                     help="Live pooled staging buffers (distinct "
                          "shape-bucket keys)")
 
+        # Route and probe tallies of the served path (obs.tally into the
+        # server's profiler): which route served each read batch, and
+        # what the planner's probe counted for the batches it routed.
+        if srv.profiler is not None:
+            def _tally(name, field):
+                def fn():
+                    return srv.profiler.tallies().get(name, (0, 0.0))[field]
+                return fn
+
+            for route in ("dense", "fused", "host_pruned"):
+                m.set_counter_fn(
+                    "service_read_batches_total", _tally(f"route.{route}", 0),
+                    {"route": route},
+                    help="Served read batches by route: dense sweep, fused "
+                         "device pruned, host pruned (mesh)")
+            for kind in ("tail", "dense", "buf"):
+                m.set_counter_fn(
+                    "planner_probe_blocks_total",
+                    _tally(f"probe.{kind}_blocks", 1), {"kind": kind},
+                    help="Posting blocks the planner's probe counted for the "
+                         "batches it routed: tail blocks, of which "
+                         "dense-bitmap, and buffer blocks")
+            m.set_counter_fn("planner_probe_entries_total",
+                             _tally("probe.entries", 1),
+                             help="Posting entries the planner's probe fed "
+                                  "the cost model")
+            m.set_counter_fn("planner_routed_batches_total",
+                             _tally("probe.entries", 0),
+                             help="Read batches the planner probed and routed")
+
     def _arena(self):
         """The live sketch arena, re-resolved per call — ingest swaps the
         host index under the ShardedIndex."""
@@ -687,9 +717,15 @@ def make_http_server(app: ServiceApp, host: str = "127.0.0.1",
     is ``httpd.server_address[1]``). Caller owns ``serve_forever`` /
     ``shutdown`` and the flush worker's ``start()``/``stop()``."""
     handler = type("BoundHandler", (_Handler,), {"app": app})
-    httpd = ThreadingHTTPServer((host, port), handler)
-    httpd.daemon_threads = True
-    return httpd
+    return _HTTPServer((host, port), handler)
+
+
+class _HTTPServer(ThreadingHTTPServer):
+    daemon_threads = True
+    # The listen backlog. socketserver's default of 5 resets connections
+    # opened in a burst (64 clients connecting at once), and each reset
+    # is a request that never gets an answer.
+    request_queue_size = 1024
 
 
 class ServiceHandle:

@@ -31,7 +31,7 @@ from repro import compat
 from repro.core.estimators import buffer_intersection, gkmv_pair_estimate
 from repro.core.hashing import PAD
 from repro.core.sketches import PackedSketches
-from repro.obs.trace import stage as obs_stage
+from repro.obs.trace import stage as obs_stage, tally as obs_tally
 from repro.parallel.sharding import logical_to_spec
 
 
@@ -407,8 +407,9 @@ class ShardedIndex:
                 "dense", np.nan, np.nan, 0,
                 "forced" if plan == "dense" else "threshold <= 0")
             return None, None
-        qp, hash_rows, bit_rows, sizes = gbkmv_plan_queries(
-            self.host, queries)
+        with obs_stage("serve.sketch", queries=len(queries)):
+            qp, hash_rows, bit_rows, sizes = gbkmv_plan_queries(
+                self.host, queries)
         with obs_stage("shard.postings", shards=self.mesh.devices.size):
             posts, offs = self._shard_postings()
         s: PackedSketches = self.host.sketches
@@ -417,6 +418,12 @@ class ShardedIndex:
             s.num_records, s.capacity, plan=plan)
         self.last_plan = decision
         self._last_plan_inputs = (hash_rows, sizes, posts)
+        # What the probe saw, as the cost model was fed it: count =
+        # planner-routed batches, sum = blocks (entries).
+        obs_tally("probe.tail_blocks", decision.tail_blocks)
+        obs_tally("probe.dense_blocks", decision.tail_dense_blocks)
+        obs_tally("probe.buf_blocks", decision.blocks - decision.tail_blocks)
+        obs_tally("probe.entries", decision.hits)
         if decision.path == "dense":
             return None, qp
 
@@ -486,6 +493,10 @@ class ShardedIndex:
         empty_scores = np.zeros(0, np.float32)
         if k <= 0 or plan == "pruned":
             hits, qp = self._pruned_batch(queries, thr, plan)
+            route = ("dense" if hits is None else
+                     "fused" if self._device_route() else "host_pruned")
+            # One count per served batch: count = batches, sum = queries.
+            obs_tally(f"route.{route}", len(queries))
             if hits is None:
                 if qp is None:
                     with obs_stage("serve.sketch", queries=len(queries)):
@@ -529,6 +540,7 @@ class ShardedIndex:
             "dense", cost_model.dense_sweep_cost(
                 s.num_records, s.capacity, len(queries)), np.nan, 0,
             "topk batch: dense sweep amortized")
+        obs_tally("route.dense", len(queries))
         with obs_stage("serve.sketch", queries=len(queries)):
             qp = batch_queries(self.host, queries)
         with obs_stage("serve.score", queries=len(queries)):

@@ -368,6 +368,39 @@ def test_http_rate_limited_connection_stays_usable():
         conn.close()
 
 
+def test_http_burst_of_new_connections_all_answer():
+    """64 clients connecting at once, as a closed-loop load does when it
+    starts: each connection is accepted and its read answered, none reset
+    by a short listen backlog."""
+    n = 64
+    start = threading.Barrier(n)
+    statuses = []
+
+    def client(address):
+        conn = http.client.HTTPConnection(*address, timeout=30)
+        start.wait()
+        try:
+            conn.request("POST", "/query", body=json.dumps(
+                {"q": [0, 1, 2], "threshold": 0.5}).encode(),
+                headers={"Content-Type": "application/json"})
+            r = conn.getresponse()
+            r.read()
+            statuses.append(r.status)
+        except OSError as e:
+            statuses.append(repr(e))
+        finally:
+            conn.close()
+
+    with serve_stub() as h:
+        threads = [threading.Thread(target=client, args=(h.address,))
+                   for _ in range(n)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    assert statuses == [200] * n
+
+
 def test_http_chunked_extensions_and_trailers():
     """Chunk-size lines with long extensions and trailer headers after
     the last chunk are valid chunked framing and must decode."""

@@ -6,8 +6,9 @@ does not fit VMEM or a primitive Mosaic cannot lower passes every
 interpret-mode test. These tests compile each kernel, and the fused query
 programs, for a v5e chip that is described but not attached, at the
 sizes of the NETFLIX deployment (480,000 records, sketch capacity 48,
-16 queries per batch), and the dense sweep at those of REUTERS. Nothing
-runs.
+16 queries per batch), the dense sweep at those of REUTERS, and both
+threshold routes at the published NETFLIX row (480,189 records, sketch
+capacity 488). Nothing runs.
 """
 
 import os
@@ -26,6 +27,15 @@ M, C, GQ, W = 480_000, 48, 16, 2
 KEYS = 70_000
 BLOCKS = 72_000
 PAYLOAD = 180_000
+
+# The NETFLIX row of Table II as the chip benchmark builds it
+# (chipbench/configs/netflix-480189.json: 480,189 records, 10 % budget,
+# r = 320): capacity 488, 10 bitmap words; its tail postings hold 1,772
+# keys, 41,926 blocks (none dense) and 1,485,610 payload words.
+NF_M, NF_C, NF_W = 480_189, 488, 10
+NF_KEYS, NF_BLOCKS, NF_PAYLOAD = 1_772, 41_926, 1_485_610
+#: One chip's HBM (chipbench/peaks.json).
+HBM_BYTES = 16e9
 
 
 @pytest.fixture(scope="module")
@@ -151,6 +161,46 @@ def test_dense_sweep_compiles_without_gathers(spec):
                        spec((GQ,), jnp.int32))
     assert "fusion" in text
     assert "gather(" not in text
+
+
+def _fits_one_chip(compiled) -> bool:
+    mem = compiled.memory_analysis()
+    return mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+
+
+def test_dense_sweep_fits_one_chip_at_the_published_netflix_count(spec):
+    """The threshold route's dense sweep over all 480,189 NETFLIX records
+    at capacity 488 for 16 queries: without gathers it fits one chip,
+    so the record count needs no cut."""
+    from repro.sketchindex.distributed import _scores_jnp
+
+    m, c, w = NF_M, NF_C, NF_W
+    compiled, text = _compile(
+        _scores_jnp, spec((m, c), jnp.uint32), spec((m,), jnp.int32),
+        spec((m,), jnp.uint32), spec((m, w), jnp.uint32),
+        spec((GQ, c), jnp.uint32), spec((GQ,), jnp.uint32),
+        spec((GQ, w), jnp.uint32), spec((GQ,), jnp.int32))
+    assert "gather(" not in text
+    assert _fits_one_chip(compiled)
+
+
+def test_fused_route_fits_one_chip_at_the_published_netflix_count(spec):
+    """The fused hit-words program of the pruned threshold route over the
+    same index and its tail postings, at the Gq 16 bucket."""
+    from repro.kernels import postings_merge as pm
+
+    m, c, w = NF_M, NF_C, NF_W
+    args = (spec((NF_KEYS,), jnp.uint32), spec((NF_KEYS + 1,), jnp.int32),
+            spec((NF_BLOCKS,), jnp.int32), spec((NF_BLOCKS,), jnp.uint32),
+            spec((NF_BLOCKS + 1,), jnp.int32),
+            spec((NF_PAYLOAD,), jnp.uint32),
+            spec((m, c), jnp.uint32), spec((m,), jnp.uint32),
+            spec((m, w), jnp.uint32), spec((GQ * (c + w + 3),), jnp.uint32))
+    compiled, _ = _compile(
+        lambda *a: pm.fused_hit_words(*a, gq=GQ, cq=c, w=w, m=m,
+                                      backend="jnp", interpret=False,
+                                      has_dense=False), *args)
+    assert _fits_one_chip(compiled)
 
 
 @pytest.fixture(scope="module")
