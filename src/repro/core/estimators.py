@@ -10,8 +10,12 @@ from __future__ import annotations
 
 import numpy as np
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core.hashing import PAD, TWO32
+
+#: Query columns the K∩ compare reads per step (``gkmv_pair_estimate``).
+QUERY_CHUNK = 8
 
 
 def _count_le(sorted_vals, lengths, bound):
@@ -45,29 +49,55 @@ def gkmv_pair_estimate(
     U₍ₖ₎ is each row's masked max, not the value at index n − 1: sorted rows
     keep their live values as a prefix, and on the TPU a per-row index lowers
     to a gather.
+
+    The query-side terms (nq, its part of U₍ₖ₎, and K∩) read the query row
+    ``QUERY_CHUNK`` columns a step, and stop past its last hash below PAD:
+    a PAD column never counts, as every bound is below PAD. The row is
+    shifted left a chunk per step rather than sliced at a traced position,
+    since under ``vmap`` that position is batched and lowers to a gather.
+    So a batch costs its widest live query row, not the padded width.
     """
     q_values = jnp.asarray(q_values, dtype=jnp.uint32)
     x_values = jnp.asarray(x_values, dtype=jnp.uint32)
     tau_pair = jnp.minimum(jnp.asarray(x_thresh, jnp.uint32),
                            jnp.asarray(q_thresh, jnp.uint32))  # [m]
-
-    nq = _count_le(q_values[None, :], None, tau_pair)          # [m] query vals ≤ τ_pair
+    zero = jnp.uint32(0)
     nx = _count_le(x_values, None, tau_pair)                   # [m]
+    ux = jnp.max(jnp.where(x_values <= tau_pair[:, None], x_values, zero),
+                 axis=-1)                                       # [m]
 
-    # Membership: each record value ≤ τ_pair that also appears in the query
-    # sketch. Both rows are sorted & duplicate-free, so equality-broadcast
-    # against the query row counts exactly the common values.
-    live = x_values <= tau_pair[:, None]                        # [m, C]
-    member = jnp.any(x_values[:, :, None] == q_values[None, None, :], axis=-1)
-    k_cap = jnp.sum(live & member, axis=-1).astype(jnp.int32)   # K∩ [m]
+    width = max(1, -(-q_values.shape[0] // QUERY_CHUNK)) * QUERY_CHUNK
+    q_rest = jnp.full((width,), PAD, jnp.uint32).at[
+        :q_values.shape[0]].set(q_values)
+
+    def more(carry):
+        return carry[0] > 0
+
+    def step(carry):
+        n_rest, q_rest, nq, uq, k_cap = carry
+        # Membership: each record value ≤ τ_pair that also appears in the
+        # chunk. Both rows are sorted & duplicate-free, so a record value
+        # matches at most one query value and the chunks' counts add up.
+        member = jnp.zeros(x_values.shape, bool)
+        for q in q_rest[:QUERY_CHUNK]:
+            in_q = q <= tau_pair                                # [m]
+            nq = nq + in_q.astype(jnp.int32)
+            uq = jnp.maximum(uq, jnp.where(in_q, q, zero))
+            member = member | (x_values == q)                   # [m, C]
+        live = x_values <= tau_pair[:, None]                    # [m, C]
+        k_cap = k_cap + jnp.sum(live & member, axis=-1).astype(jnp.int32)
+        q_rest = jnp.concatenate(
+            [q_rest[QUERY_CHUNK:], jnp.full((QUERY_CHUNK,), PAD, jnp.uint32)])
+        return n_rest - QUERY_CHUNK, q_rest, nq, uq, k_cap
+
+    zeros = jnp.zeros(x_values.shape[:1], jnp.int32)
+    _, _, nq, uq, k_cap = lax.while_loop(more, step, (
+        jnp.sum(q_values < PAD, dtype=jnp.int32), q_rest,
+        zeros, jnp.zeros_like(ux), zeros))                      # [m] each
 
     k = nq + nx - k_cap                                         # |L_Q ∪ L_X| [m]
 
     # U_(k): largest hash ≤ τ_pair in either row, 0 where neither has one.
-    zero = jnp.uint32(0)
-    uq = jnp.max(jnp.where(q_values[None, :] <= tau_pair[:, None],
-                           q_values[None, :], zero), axis=-1)   # [m]
-    ux = jnp.max(jnp.where(live, x_values, zero), axis=-1)      # [m]
     u = jnp.maximum(uq, ux)
     u_unit = (u.astype(jnp.float32) + 1.0) / TWO32
 
@@ -81,11 +111,18 @@ def gkmv_pair_estimate(
     return d_hat, k, k_cap
 
 
+def query_cols(lengths) -> int:
+    """Query columns :func:`gkmv_pair_estimate` compares for a batch whose
+    rows hold ``lengths`` live hashes: the widest, rounded up to
+    ``QUERY_CHUNK`` (0 for a batch with none)."""
+    widest = int(np.max(lengths, initial=0))
+    return -(-widest // QUERY_CHUNK) * QUERY_CHUNK
+
+
 def buffer_intersection(q_buf, x_buf):
     """|H_Q ∩ H_X| via AND + popcount. q_buf uint32[W], x_buf uint32[m, W]."""
     if x_buf.shape[-1] == 0:
         return jnp.zeros(x_buf.shape[0], dtype=jnp.int32)
-    from jax import lax
     inter = jnp.bitwise_and(x_buf, q_buf[None, :])
     return jnp.sum(lax.population_count(inter), axis=-1).astype(jnp.int32)
 

@@ -385,9 +385,10 @@ class ServiceApp:
                     help="Live pooled staging buffers (distinct "
                          "shape-bucket keys)")
 
-        # Route and probe tallies of the served path (obs.tally into the
-        # server's profiler): which route served each read batch, and
-        # what the planner's probe counted for the batches it routed.
+        # Route, probe and sweep tallies of the served path (obs.tally
+        # into the server's profiler): which route served each read batch,
+        # what the planner's probe counted for the batches it routed, and
+        # the query columns each dense sweep compared.
         if srv.profiler is not None:
             def _tally(name, field):
                 def fn():
@@ -414,6 +415,14 @@ class ServiceApp:
             m.set_counter_fn("planner_routed_batches_total",
                              _tally("probe.entries", 0),
                              help="Read batches the planner probed and routed")
+            m.set_counter_fn("dense_sweeps_total",
+                             _tally("sweep.query_cols", 0),
+                             help="Dense sweeps of served read batches")
+            m.set_counter_fn("dense_sweep_query_cols_total",
+                             _tally("sweep.query_cols", 1),
+                             help="Query columns the dense sweeps' K∩ compare "
+                                  "read: each batch's widest live query row, "
+                                  "rounded up to the compare's chunk")
 
     def _arena(self):
         """The live sketch arena, re-resolved per call — ingest swaps the

@@ -28,7 +28,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import compat
-from repro.core.estimators import buffer_intersection, gkmv_pair_estimate
+from repro.core.estimators import (buffer_intersection, gkmv_pair_estimate,
+                                   query_cols)
 from repro.core.hashing import PAD
 from repro.core.sketches import PackedSketches
 from repro.obs.trace import stage as obs_stage, tally as obs_tally
@@ -445,6 +446,15 @@ class ShardedIndex:
         return ids, qp
 
     # -- scoring --
+    def _dense_scores(self, qp: PackedSketches):
+        """The dense sweep of a served batch. One tally per sweep: count =
+        sweeps, sum = the query columns its K∩ compare reads (the jnp
+        sweep stops past the widest live row; the others read them all)."""
+        obs_tally("sweep.query_cols",
+                  query_cols(qp.lengths) if self.backend == "jnp"
+                  else qp.values.shape[1])
+        return score_batch(self.didx, qp, backend=self.backend)
+
     def batch_scores(self, queries) -> np.ndarray:
         """f32[m, Gq] (padding rows trimmed) — one sharded index sweep."""
         qp = batch_queries(self.host, [np.asarray(q) for q in queries])
@@ -502,8 +512,7 @@ class ShardedIndex:
                     with obs_stage("serve.sketch", queries=len(queries)):
                         qp = batch_queries(self.host, queries)
                 with obs_stage("serve.score", queries=len(queries)) as span:
-                    scores = span.sync(score_batch(
-                        self.didx, qp, backend=self.backend))
+                    scores = span.sync(self._dense_scores(qp))
                 with obs_stage("serve.hits"):
                     hits = threshold_hits_packed(
                         scores[: self.num_records], thr)
@@ -544,7 +553,7 @@ class ShardedIndex:
         with obs_stage("serve.sketch", queries=len(queries)):
             qp = batch_queries(self.host, queries)
         with obs_stage("serve.score", queries=len(queries)):
-            scores = score_batch(self.didx, qp, backend=self.backend)
+            scores = self._dense_scores(qp)
         with obs_stage("serve.topk", k=k):
             vals, ids = distributed_topk(scores, k, self.mesh)
             jax.block_until_ready(vals)

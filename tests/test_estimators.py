@@ -231,3 +231,115 @@ def test_dense_sweep_scores_match_numpy_twin(seed):
                      for g in range(gq)], axis=-1)
     assert want.max() > 0
     np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+
+
+def _pair_estimate_full_width(q_values, q_thresh, x_values, x_thresh):
+    """The former ``gkmv_pair_estimate``: nq, U_(k) and K∩ over every column
+    of the PAD-padded query row, K∩ by one broadcast compare. The oracle of
+    the form bounded by the query's live width."""
+    q_values = jnp.asarray(q_values, dtype=jnp.uint32)
+    x_values = jnp.asarray(x_values, dtype=jnp.uint32)
+    tau_pair = jnp.minimum(jnp.asarray(x_thresh, jnp.uint32),
+                           jnp.asarray(q_thresh, jnp.uint32))
+    nq = jnp.sum(q_values[None, :] <= tau_pair[:, None], axis=-1).astype(jnp.int32)
+    nx = jnp.sum(x_values <= tau_pair[:, None], axis=-1).astype(jnp.int32)
+    live = x_values <= tau_pair[:, None]
+    member = jnp.any(x_values[:, :, None] == q_values[None, None, :], axis=-1)
+    k_cap = jnp.sum(live & member, axis=-1).astype(jnp.int32)
+    k = nq + nx - k_cap
+    zero = jnp.uint32(0)
+    uq = jnp.max(jnp.where(q_values[None, :] <= tau_pair[:, None],
+                           q_values[None, :], zero), axis=-1)
+    ux = jnp.max(jnp.where(live, x_values, zero), axis=-1)
+    u = jnp.maximum(uq, ux)
+    u_unit = (u.astype(jnp.float32) + 1.0) / TWO32
+    valid = (k >= 2) & (k_cap >= 1)
+    d_hat = jnp.where(
+        valid,
+        (k_cap.astype(jnp.float32) / jnp.maximum(k, 1).astype(jnp.float32))
+        * ((k.astype(jnp.float32) - 1.0) / jnp.maximum(u_unit, 1e-30)),
+        jnp.where(k_cap >= 1, k_cap.astype(jnp.float32), 0.0),
+    )
+    return d_hat, k, k_cap
+
+
+#: Capacity of the bounded-width cases: three compare chunks.
+CAP = 24
+
+
+def _overflow_case(rng, m, widths):
+    """Records and queries packed by ``pack_csr`` at capacity ``CAP``.
+
+    About a third of the m records hold more hashes than fit: they keep
+    their smallest and lower their threshold to the largest kept. Query g
+    holds ``widths[g]`` live hashes, half of them drawn from a record it
+    overlaps. Returns (x PackedSketches, q values, q thresholds)."""
+    from repro.core.sketches import pack_csr
+
+    pool = np.unique(hash_u32_np(np.arange(4_000)))
+    rows = [np.sort(rng.choice(pool, size=int(rng.integers(0, 2 * CAP)),
+                               replace=False)) for _ in range(m)]
+    tau = np.uint32(pool.max())
+    x = pack_csr(np.concatenate(rows), np.repeat(np.arange(m), [len(r) for r in rows]),
+                 m, np.full(m, tau, np.uint32), np.array([len(r) for r in rows]),
+                 capacity=CAP)
+    assert (x.thresh < tau).any() and (x.thresh == tau).any()
+    qv = np.full((len(widths), CAP), PAD, np.uint32)
+    for g, w in enumerate(widths):
+        near = rows[g % m][: w // 2]
+        rest = rng.choice(np.setdiff1d(pool, near), size=w - len(near),
+                          replace=False)
+        qv[g, :w] = np.sort(np.concatenate([near, rest]))
+    qt = rng.integers(2**31, 2**32 - 1, size=len(widths), dtype=np.uint32)
+    qt[::2] = tau
+    return x, qv, qt
+
+
+@pytest.mark.parametrize("width", [0, 1, 7, 8, 9, 16, CAP - 1, CAP])
+def test_bounded_query_width_matches_full_width(width):
+    """K∩, nq and U_(k) over the query's live columns only give the full
+    broadcast form's d_hat, k and k_cap bit for bit, beside records whose
+    threshold capacity overflow lowered."""
+    rng = np.random.default_rng(100 + width)
+    x, qv, qt = _overflow_case(rng, 64, [width])
+    args = (jnp.asarray(qv[0]), qt[0], jnp.asarray(x.values),
+            jnp.asarray(x.thresh))
+    got = gkmv_pair_estimate(args[0], None, args[1], args[2], None, args[3])
+    want = _pair_estimate_full_width(*args)
+    np.testing.assert_array_equal(np.asarray(got[0]).view(np.uint32),
+                                  np.asarray(want[0]).view(np.uint32))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    np.testing.assert_array_equal(np.asarray(got[2]), np.asarray(want[2]))
+    assert width == 0 or np.asarray(want[2]).max() > 0
+
+
+@pytest.mark.parametrize("widths", [
+    [5], [0, CAP], [0, 1, 7, 8, 9, 16, CAP - 1, CAP, 3, 2, 12, 17, 4, 0, 6, 1],
+], ids=["gq1", "gq2", "gq16"])
+def test_dense_sweep_bounded_width_matches_full_width(widths):
+    """``_scores_jnp`` over a batch of mixed live widths (the batch's
+    widest row bounds every query's compare) scores as the full broadcast
+    form does, bit for bit."""
+    import jax
+
+    from repro.sketchindex.distributed import _scores_jnp
+
+    rng = np.random.default_rng(len(widths))
+    x, qv, qt = _overflow_case(rng, 200, widths)
+    w = 2
+    xb = rng.integers(0, 2**32, size=(x.num_records, w), dtype=np.uint32)
+    qb = rng.integers(0, 2**32, size=(len(widths), w), dtype=np.uint32)
+    qs = (np.asarray(widths) + 5).astype(np.int32)
+    xs = [jnp.asarray(a) for a in (x.values, x.lengths, x.thresh, xb)]
+    qs_ = [jnp.asarray(a) for a in (qv, qt, qb, qs)]
+    got = np.asarray(jax.jit(_scores_jnp)(*xs, *qs_))
+
+    def one_query(v, t, b, s):
+        d_hat, _, _ = _pair_estimate_full_width(v, t, xs[0], xs[2])
+        o1 = buffer_intersection(b, xs[3])
+        return (o1.astype(jnp.float32) + d_hat) / jnp.maximum(
+            s.astype(jnp.float32), 1.0)
+
+    want = np.asarray(jax.jit(jax.vmap(one_query))(*qs_).T)
+    assert (want > 0).any()
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))

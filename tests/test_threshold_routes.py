@@ -189,3 +189,54 @@ def test_a_raise_in_the_tallies_costs_no_answer(corpus, served,
         ["route.fused", "probe.tail_blocks", "probe.dense_blocks",
          "probe.buf_blocks", "probe.entries"])
     assert obs.faults() - before == len(raised)
+
+
+def _query_cols(lengths):
+    """The batch's widest live query row, rounded up to the compare's
+    chunk."""
+    from repro.core.estimators import QUERY_CHUNK
+
+    return -(-int(np.max(lengths)) // QUERY_CHUNK) * QUERY_CHUNK
+
+
+def test_each_dense_sweep_tallies_its_query_columns(corpus, served):
+    """A dense flush tallies one sweep of the batch's widest live query
+    row, rounded up to the chunk, and /metrics exports both numbers; a
+    pruned flush sweeps nothing."""
+    from repro.sketchindex.distributed import batch_queries
+
+    _, _, _, queries, _ = corpus
+    for (plan, backend), (srv, app, _) in served.items():
+        tallies = srv.profiler.tallies()
+        metrics = parse_prometheus(app.metrics.render())
+        if srv.index.last_plan.path != "dense":
+            assert "sweep.query_cols" not in tallies, plan
+            assert metrics["dense_sweeps_total"] == 0
+            continue
+        qp = batch_queries(srv.index.host, queries)
+        cols = _query_cols(qp.lengths)
+        assert 0 < cols < qp.values.shape[1]
+        assert tallies["sweep.query_cols"] == (1, float(cols))
+        assert metrics["dense_sweeps_total"] == 1
+        assert metrics["dense_sweep_query_cols_total"] == cols
+
+
+def test_topk_sweeps_tally_their_query_columns(corpus):
+    """Top-k batches of one and of three queries through ``serve_batch``:
+    two sweeps, each of its own batch's widest live row."""
+    from repro import obs
+    from repro.obs.profile import StageProfiler
+    from repro.sketchindex.distributed import batch_queries
+
+    recs, index, _, _, _ = corpus
+    sharded = _sharded(index)
+    batches = [[recs[10]], [recs[11], recs[12], recs[13]]]
+    prof = StageProfiler()
+    with obs.attach(profiler=prof):
+        for qs in batches:
+            out = sharded.serve_batch(qs, np.inf, k=5)
+            assert all(len(o["topk_ids"]) == 5 for o in out)
+    cols = [_query_cols(batch_queries(sharded.host, qs).lengths)
+            for qs in batches]
+    assert cols[0] > 0
+    assert prof.tallies()["sweep.query_cols"] == (2, float(sum(cols)))

@@ -12,6 +12,7 @@ capacity 488). Nothing runs.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -147,20 +148,41 @@ def test_fused_query_program_compiles(spec, backend, head):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
 
 
-def test_dense_sweep_compiles_without_gathers(spec):
-    """The served dense sweep at the REUTERS widths (833,081 records,
-    capacity 72, 16 queries): a per-row index read of U_(k) would lower
-    to a gather over every (query, record) pair."""
+#: The REUTERS row of Table II as the chip benchmark builds it
+#: (chipbench/configs/reuters-833k.json): 833,081 records, capacity 72.
+RT_M, RT_C = 833_081, 72
+
+
+@pytest.fixture(scope="module")
+def reuters_sweep(spec):
+    """HLO of the served dense sweep at the REUTERS widths, 16 queries."""
     from repro.sketchindex.distributed import _scores_jnp
 
-    m, c, w = 833_081, 72, 1
+    m, c, w = RT_M, RT_C, 1
     _, text = _compile(_scores_jnp, spec((m, c), jnp.uint32),
                        spec((m,), jnp.int32), spec((m,), jnp.uint32),
                        spec((m, w), jnp.uint32), spec((GQ, c), jnp.uint32),
                        spec((GQ,), jnp.uint32), spec((GQ, w), jnp.uint32),
                        spec((GQ,), jnp.int32))
-    assert "fusion" in text
-    assert "gather(" not in text
+    return text
+
+
+def test_dense_sweep_compiles_without_gathers(reuters_sweep):
+    """The served dense sweep at the REUTERS widths (833,081 records,
+    capacity 72, 16 queries): a per-row index read of U_(k) would lower
+    to a gather over every (query, record) pair."""
+    assert "fusion" in reuters_sweep
+    assert "gather(" not in reuters_sweep
+
+
+def test_dense_sweep_compares_no_padded_query_axis(reuters_sweep):
+    """The K∩ compare walks the query row in chunks, in a loop whose trip
+    count is the batch's widest live row: no array of the sweep carries
+    a query axis beside the record's 72 columns, as the broadcast of
+    every record column against every padded query column did."""
+    assert re.search(rf"\[{GQ},{RT_M},{RT_C},\d+\]", reuters_sweep) is None
+    assert re.search(rf"\[{GQ},{RT_M},{RT_C}\]", reuters_sweep)
+    assert " while(" in reuters_sweep
 
 
 def _fits_one_chip(compiled) -> bool:
