@@ -43,7 +43,7 @@ from repro import api
 from repro.data.synth import generate_dataset, make_query_workload
 from repro.obs import StageProfiler, attach
 from repro.planner import candidates_for
-from repro.planner.plan import probe_hits_per_query, unpack_query_rows
+from repro.planner.plan import probe_hits_per_query
 
 THRESHOLDS = (0.5, 0.7, 0.9)
 TOPK_KS = (10, 100)
@@ -202,7 +202,7 @@ def run(quick: bool = True, json_out: str | None = None,
     # host filter's candidate-set sizes and the probe's posting-entry
     # counts (the device path never materializes candidates on host).
     _, hash_rows, bit_rows, q_sizes = index._plan_queries(queries)
-    post = index._postings()
+    post = index._sketch_pack().postings()
     probe = probe_hits_per_query(post, hash_rows, bit_rows)
 
     # Space accounting for the block-compressed postings: at-rest bytes
@@ -335,8 +335,7 @@ def run(quick: bool = True, json_out: str | None = None,
                         for q in queries]
                 bsub = _batches(qsub)
                 dt = _time_path(index, bsub, 0.7, "pruned")
-                qp_sub = index._query_pack(qsub)
-                h_sub, b_sub, _ = unpack_query_rows(qp_sub)
+                _, h_sub, b_sub, _ = index._plan_queries(qsub)
                 per = probe_hits_per_query(post, h_sub, b_sub)
                 cal_rows.append({
                     "qps_pruned": nq / dt,

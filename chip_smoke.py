@@ -138,10 +138,10 @@ def _tol(x) -> float:
 
 def check_hits(served, scores, t, what):
     """Served hit ids vs the reference; returns the slack-case count."""
-    from repro.planner.prune import f32_threshold, threshold_hits_packed
+    from repro.planner.prune import f32_threshold
 
-    want = threshold_hits_packed(scores, t)
     thr = float(f32_threshold(t))
+    want = [np.nonzero(col >= np.float32(thr))[0] for col in scores.T]
     slack = 0
     for g, (got, ref) in enumerate(zip(served, want)):
         for rid in np.setxor1d(got, ref):
@@ -410,6 +410,7 @@ def pallas_programs(sharded) -> dict:
     a Pallas kernel (``tpu_custom_call``) rather than the interpreter."""
     from repro.kernels import interpret_mode, postings_merge
     from repro.planner import device as planner_device
+    from repro.planner import route
     from repro.sketchindex import batch_queries
     from repro.sketchindex.distributed import _score_batch_jit
 
@@ -418,7 +419,7 @@ def pallas_programs(sharded) -> dict:
     dense = _score_batch_jit.lower(sharded.didx, qp, "pallas",
                                    sharded.mesh).compile()
     out["dense scorer"] = "tpu_custom_call" in dense.as_text()
-    if sharded._device_route():
+    if route.fused(sharded):
         arena = sharded.host.sketches
         dpost, dpack, sq = planner_device.stage_query_inputs(arena, qp, 0.5)
         fused = postings_merge.fused_hit_words.lower(

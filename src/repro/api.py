@@ -13,12 +13,14 @@ single evaluation door):
     index.save(path); repro.api.load_index(path)    # npz round-trip
     index.nbytes()                                  # space accounting
 
-Sketch engines (gbkmv/gkmv/kmv) route ``query``/``batch_query`` through
-the candidate-pruning planner (:mod:`repro.planner`): ``plan="auto"``
-(default) lets a cost model pick between the dense index sweep and the
-inverted-postings filter-and-verify path per batch; ``plan="dense"`` /
-``plan="pruned"`` force a path. Both return identical candidate sets —
-pruning is exact under the estimator's containment bound.
+Sketch engines (gbkmv/gkmv/kmv) answer ``query``/``batch_query``/``topk``
+and ``serve_batch`` through the planner's router
+(:mod:`repro.planner.route`): ``plan="auto"`` (default) lets a cost model
+pick between the dense index sweep and the inverted-postings
+filter-and-verify path per threshold batch, and keeps top-k on the
+sweep; ``plan="dense"`` / ``plan="pruned"`` force a path. Every route
+returns identical answers — pruning is exact under the estimator's
+containment bound.
 
 Registered engines: ``gbkmv``, ``gkmv``, ``kmv`` (the paper's sketches),
 ``lshe`` (LSH Ensemble baseline), ``exact`` and ``prefix`` (ground-truth
@@ -37,7 +39,6 @@ dim sharded over a device mesh.
 
 from __future__ import annotations
 
-from time import perf_counter
 from typing import Protocol, runtime_checkable
 
 import numpy as np
@@ -52,6 +53,8 @@ from repro.core.arena import SketchArena
 from repro.core.estimators import containment_matrix, normalize_backend
 from repro.core.hashing import PAD, hash_u32_np
 from repro.core.sketches import PackedSketches
+from repro.planner.plan import unpack_query_rows
+from repro.planner.route import Routed
 
 
 @runtime_checkable
@@ -243,9 +246,6 @@ class _IndexBase:
         records = list(self._records) + [np.asarray(r) for r in new_records]
         rebuilt = get_engine(self.engine).build(records, **self._build_cfg)
         self.__dict__.update(rebuilt.__dict__)
-        # Planner postings describe the pre-rebuild sketches; drop them
-        # (the fresh build may not have touched the cache key).
-        self._post = None
         return self
 
     def save(self, path: str) -> None:
@@ -348,188 +348,52 @@ def _maybe_eager_postings(sketches, postings: str) -> None:
             arena.postings()
 
 
-class _PlannedIndexMixin:
-    """Planner routing for sketch-backed indexes (gbkmv/gkmv/kmv).
+class _PlannedIndexMixin(Routed):
+    """Routing for the sketch-backed indexes (gbkmv/gkmv/kmv).
 
-    ``query``/``batch_query``/``topk`` accept ``plan`` ∈ {"auto",
-    "dense", "pruned"}: "auto" (default) asks :mod:`repro.planner` to
-    pick the cheaper path per batch from posting selectivity; forced
-    modes pin it. Both paths return identical results. ``topk`` routes
-    through postings-driven upper-bound pruning (the running k-th score
-    is the moving threshold) with exact parity against the dense
-    ranking. Postings live ON the arena (:class:`SketchArena`) — built
-    lazily on first planned query, shared with every other layer
-    viewing the same arena, and maintained incrementally across
+    ``query``/``batch_query``/``topk``/``serve_batch`` answer through the
+    router (:mod:`repro.planner.route`) and accept ``plan`` ∈ {"auto",
+    "dense", "pruned"}: "auto" (default) lets the cost model pick the
+    dense sweep or filter-and-verify for threshold batches and keeps
+    top-k on the sweep; forced modes pin the route. Every route returns
+    identical results; pruned top-k uses the running k-th score as a
+    moving threshold. Postings live ON the arena (:class:`SketchArena`)
+    — built lazily on the first planned query, shared with every other
+    layer viewing the same arena, and maintained incrementally across
     ``insert``.
 
-    With ``backend`` ∈ {"jnp", "pallas"} the pruned threshold path runs
-    device-resident: candidate merge (kernels/postings_merge.py),
-    gather-scoring, and packed thresholding all execute on device with
-    no host-numpy transfer in between (``planner.device``).
+    With ``backend`` ∈ {"jnp", "pallas"}, GB-KMV and G-KMV run the pruned
+    routes device-resident (``planner.device``).
 
-    Subclasses provide ``_sketch_pack`` (the sketch arena),
-    ``_plan_queries`` (per-query retained hashes / buffer bits / sizes
-    + the scoring pack), and ``_pair_score_fn`` (ragged verify scorer).
+    Subclasses provide ``_sketch_pack`` (the sketch arena) and
+    ``_sketch`` (the batch's query pack); the hooks below serve every
+    engine whose pack ``containment_matrix`` and ``gather_score`` read.
     """
 
-    last_plan = None            # QueryPlan of the most recent planned batch
-    last_candidate_sizes: list | None = None
-    last_explain: list | None = None   # explain dicts of the last explained batch
-    _device_prunable = False    # engine scoring has a device twin
+    def _query_rows(self, qp):
+        return unpack_query_rows(qp)
 
-    def _sketch_pack(self) -> PackedSketches:
-        raise NotImplementedError
+    def _shard_postings(self):
+        return [self._sketch_pack().postings()], [0]
 
-    def _plan_queries(self, queries):
-        raise NotImplementedError
+    def _dense_scores(self, qp):
+        # Device-resident for jnp/pallas: the hit compare runs there.
+        return containment_matrix(qp, self._sketch_pack(),
+                                  backend=self.backend, as_numpy=False)
 
-    def _pair_score_fn(self, qp):
-        raise NotImplementedError
+    def _dense_topk(self, scores, k: int):
+        from repro.planner import topk_select
 
-    def _dense_batch_query(self, queries, threshold,
-                           qp=None) -> list[np.ndarray]:
-        """``qp``: query pack already built by _plan_queries (auto-routed
-        dense batches must not pay the sketching twice)."""
-        raise NotImplementedError
+        s = np.asarray(scores)
+        ids = np.arange(self.num_records, dtype=np.int64)
+        return [topk_select(ids, s[:, g], k, len(ids))
+                for g in range(s.shape[1])]
 
-    # Postings are owned by the arena, not the wrapper: every layer that
-    # views the same arena (api index, ShardedIndex, server) shares one
-    # inverted index. The property keeps the legacy ``self._post`` spelling
-    # working (tests and the rebuild-fallback insert assign through it).
-    @property
-    def _post(self):
-        return getattr(self._sketch_pack(), "_post", None)
+    def _score_pairs(self, qp, cand_rec, cand_q):
+        from repro.kernels import gather_score
 
-    @_post.setter
-    def _post(self, value):
-        arena = self._sketch_pack()
-        if value is None:
-            if isinstance(arena, SketchArena):
-                arena.clear_postings()
-        else:
-            arena.install_postings(value)
-
-    def _postings(self):
-        return SketchArena.from_pack(self._sketch_pack()).postings()
-
-    def query(self, q_ids, threshold: float, *, plan: str = "auto",
-              explain: bool = False):
-        if explain:
-            ids, ex = self.batch_query([q_ids], threshold, plan=plan,
-                                       explain=True)
-            return ids[0], ex[0]
-        return self.batch_query([q_ids], threshold, plan=plan)[0]
-
-    def _explained(self, hits, *, threshold, t0, cands=None,
-                   hash_rows=None, sizes=None, posts=None):
-        """Pair results with per-query explain dicts (explain=True)."""
-        from repro import obs
-
-        ex = obs.build_explain(
-            self.last_plan, engine=self.engine, backend=self.backend,
-            threshold=threshold, n_queries=len(hits), hits=hits,
-            cands=cands, hash_rows=hash_rows, sizes=sizes, posts=posts,
-            measured_seconds=perf_counter() - t0)
-        self.last_explain = ex
-        return hits, ex
-
-    def batch_query(self, queries, threshold: float, *,
-                    plan: str = "auto", explain: bool = False):
-        """Planned batch query. With ``explain=True`` returns
-        ``(hits, explains)`` — one explain dict per query (see
-        :mod:`repro.obs.explain`); the device-backend pruned path reruns
-        the host candidate accounting to fill it (EXPLAIN ANALYZE
-        semantics: asking costs extra, answers don't change)."""
-        from repro import obs, planner
-
-        plan = planner.normalize_plan(plan)
-        queries = [np.asarray(q) for q in queries]
-        if not queries:
-            return ([], []) if explain else []
-        t0 = perf_counter()
-        if plan == "dense" or float(threshold) <= 0.0:
-            self.last_plan = planner.QueryPlan(
-                "dense", np.nan, np.nan, 0,
-                "forced" if plan == "dense" else "threshold <= 0")
-            with obs.stage("planner.dense", queries=len(queries)):
-                ids = self._dense_batch_query(queries, threshold)
-            if explain:
-                return self._explained(ids, threshold=threshold, t0=t0)
-            return ids
-        with obs.stage("planner.sketch", queries=len(queries)):
-            qp, hash_rows, bit_rows, sizes = self._plan_queries(queries)
-        s = self._sketch_pack()
-        decision = planner.choose_plan(
-            self._postings(), hash_rows, bit_rows, threshold,
-            s.num_records, s.capacity, plan=plan)
-        self.last_plan = decision
-        cands = None
-        if decision.path == "dense":
-            with obs.stage("planner.dense", queries=len(queries)):
-                ids = self._dense_batch_query(queries, threshold, qp=qp)
-        elif self._device_prunable and self.backend in ("jnp", "pallas"):
-            from repro.planner import device as planner_device
-
-            # The device path never materializes per-query candidate
-            # sets on host — only the probe breakdown is known
-            # (decision.per_query_hits); candidate accounting stays None.
-            self.last_candidate_sizes = None
-            ids = planner_device.pruned_batch_device(
-                SketchArena.from_pack(s), qp, threshold,
-                plan=decision, backend=self.backend)
-            if explain:
-                # Host accounting pass the device path skipped.
-                gen = planner.merged_candidates(self._postings())
-                cands = [gen(qh, qb, float(threshold), int(qs))
-                         for qh, qb, qs in zip(hash_rows, bit_rows, sizes)]
-        else:
-            ids, cands = planner.pruned_batch(
-                self._post, hash_rows, bit_rows, sizes, threshold,
-                self._pair_score_fn(qp))
-            self.last_candidate_sizes = [len(c.rec_ids) for c in cands]
-        if explain:
-            return self._explained(
-                ids, threshold=threshold, t0=t0, cands=cands,
-                hash_rows=hash_rows, sizes=sizes, posts=self._postings())
-        return ids
-
-    def topk(self, q_ids, k: int, *,
-             plan: str = "auto") -> tuple[np.ndarray, np.ndarray]:
-        """Planner-aware top-k: postings-driven upper-bound pruning with
-        the running k-th score as the moving threshold — exact parity
-        with the dense ranking under the deterministic (-score, id)
-        order (``plan="dense"`` forces the full sweep)."""
-        from repro import planner
-
-        plan = planner.normalize_plan(plan)
-        s = self._sketch_pack()
-        if plan == "dense" or int(k) <= 0 or s.num_records == 0:
-            return super().topk(q_ids, k)
-        qp, hash_rows, bit_rows, sizes = self._plan_queries(
-            [np.asarray(q_ids)])
-        if plan == "auto":
-            decision = planner.choose_plan(
-                self._postings(), hash_rows, bit_rows, 1.0,
-                s.num_records, s.capacity)
-            self.last_plan = decision
-            if decision.path == "dense":
-                return super().topk(q_ids, k)
-        else:
-            # Forced pruned: record the route like batch_query does, so
-            # serving drift accounting sees every planned execution.
-            self.last_plan = planner.QueryPlan(
-                "pruned", np.nan, np.nan, 0, "forced topk")
-        if self._device_prunable and self.backend in ("jnp", "pallas"):
-            from repro.planner import device as planner_device
-
-            # Fully device-resident: fused probe→decode→score→lax.top_k,
-            # one readback of the [1, k] result pair.
-            ids, scores = planner_device.pruned_topk_device(
-                SketchArena.from_pack(s), qp, k, backend=self.backend)[0]
-            return ids, scores
-        return planner.pruned_topk(
-            self._postings(), hash_rows[0], bit_rows[0], int(sizes[0]), k,
-            self._pair_score_fn(qp), s.num_records)
+        return gather_score.score_pairs(self._sketch_pack(), qp, cand_rec,
+                                        cand_q, backend=self.backend)
 
 
 # ---------------------------------------------------------------------------
@@ -608,40 +472,19 @@ class GBKMVApiIndex(_PlannedIndexMixin, _IndexBase):
         q = gbkmv_mod.sketch_query(self.core, np.asarray(q_ids))
         return gbkmv_mod.containment_scores(self.core, q, backend=self.backend)
 
-    # -- planner plumbing --
+    # -- router hooks --
     def _sketch_pack(self) -> PackedSketches:
         return self.core.sketches
 
-    def _query_pack(self, queries) -> PackedSketches:
+    def _sketch(self, queries) -> PackedSketches:
         from repro.sketchindex.distributed import batch_queries
 
         return batch_queries(self.core, queries)
 
-    def _plan_queries(self, queries):
-        from repro.planner.plan import gbkmv_plan_queries
-
-        return gbkmv_plan_queries(self.core, queries)
-
-    def _pair_score_fn(self, qp):
-        from repro.kernels import gather_score
-
-        return lambda cand_rec, cand_q: gather_score.score_pairs(
-            self._sketch_pack(), qp, cand_rec, cand_q, backend=self.backend)
-
-    def _dense_batch_query(self, queries, threshold,
-                           qp=None) -> list[np.ndarray]:
-        from repro.planner.prune import threshold_hits_packed
-
-        if qp is None:
-            qp = self._query_pack(queries)
-        s = containment_matrix(qp, self.core.sketches, backend=self.backend,
-                               as_numpy=False)     # device-resident for jnp/pallas
-        return threshold_hits_packed(s, threshold)
-
     def batch_scores(self, queries) -> np.ndarray:
         """f32[m, Gq] — one index sweep for a whole query batch."""
-        qp = self._query_pack([np.asarray(q) for q in queries])
-        return containment_matrix(qp, self.core.sketches, backend=self.backend)
+        qp = self._sketch([np.asarray(q) for q in queries])
+        return np.asarray(self._dense_scores(qp))
 
     def insert(self, new_records, budget: int | None = None):
         """Paper §IV-B dynamic maintenance: τ-retighten, never re-hash old
@@ -741,37 +584,14 @@ class GKMVApiIndex(_PlannedIndexMixin, _IndexBase):
                                   capacity=self.sketches.capacity)
         return containment_matrix(q, self.sketches, backend=self.backend)[:, 0]
 
-    # -- planner plumbing --
+    # -- router hooks --
     def _sketch_pack(self) -> PackedSketches:
         return self.sketches
 
-    def _query_pack(self, queries) -> PackedSketches:
+    def _sketch(self, queries) -> PackedSketches:
         return gkmv_mod.sketch_query_batch(
             queries, self.tau, seed=self.seed,
             capacity=self.sketches.capacity)
-
-    def _plan_queries(self, queries):
-        qp = self._query_pack(queries)
-        vals, lens = np.asarray(qp.values), np.asarray(qp.lengths)
-        hash_rows = [vals[g, : lens[g]] for g in range(len(queries))]
-        bit_rows = [np.zeros(0, np.int64)] * len(queries)   # no buffer
-        return qp, hash_rows, bit_rows, np.asarray(qp.sizes)
-
-    def _pair_score_fn(self, qp):
-        from repro.kernels import gather_score
-
-        return lambda cand_rec, cand_q: gather_score.score_pairs(
-            self.sketches, qp, cand_rec, cand_q, backend=self.backend)
-
-    def _dense_batch_query(self, queries, threshold,
-                           qp=None) -> list[np.ndarray]:
-        from repro.planner.prune import threshold_hits_packed
-
-        if qp is None:
-            qp = self._query_pack(queries)
-        s = containment_matrix(qp, self.sketches, backend=self.backend,
-                               as_numpy=False)
-        return threshold_hits_packed(s, threshold)
 
     def save(self, path: str) -> None:
         np.savez_compressed(path, engine="gkmv", tau=np.uint32(self.tau),
@@ -860,42 +680,33 @@ class KMVApiIndex(_PlannedIndexMixin, _IndexBase):
             jnp.asarray(xv), jnp.asarray(xl))
         return np.asarray(d_hat) / max(q_len, 1)
 
-    # -- planner plumbing --
+    # -- router hooks: a query pack is (k smallest hashes, |Q|) per query --
     def _sketch_pack(self) -> PackedSketches:
         return self.sketches
 
-    def _plan_queries(self, queries):
-        hash_rows = [self._query_sketch(q) for q in queries]
-        bit_rows = [np.zeros(0, np.int64)] * len(queries)
-        sizes = np.asarray([len(q) for q in queries], np.int64)
-        return (hash_rows, sizes), hash_rows, bit_rows, sizes
+    def _sketch(self, queries):
+        return ([self._query_sketch(q) for q in queries],
+                np.asarray([len(q) for q in queries], np.int64))
 
-    def _pair_score_fn(self, qp):
+    def _query_rows(self, qp):
         hash_rows, sizes = qp
+        return hash_rows, [np.zeros(0, np.int64)] * len(hash_rows), sizes
 
-        def score(cand_rec, cand_q):
-            out = np.zeros(len(cand_rec), np.float32)
-            for g in np.unique(cand_q):
-                sel = np.nonzero(cand_q == g)[0]
-                out[sel] = self._scores_rows(
-                    hash_rows[g], int(sizes[g]), rows=cand_rec[sel])
-            return out
+    def _sweep_cols(self, qp) -> int:
+        return self.sketches.capacity          # the KMV sweep reads them all
 
-        return score
+    def _dense_scores(self, qp):
+        return np.stack([self._scores_rows(h, int(n), rows=None)
+                         for h, n in zip(*qp)], axis=-1)
 
-    def _dense_batch_query(self, queries, threshold,
-                           qp=None) -> list[np.ndarray]:
-        from repro.planner.prune import threshold_hits_packed
-
-        if qp is not None:                    # query sketches already hashed
-            hash_rows, sizes = qp
-            cols = [self._scores_rows(h, int(n), rows=None)
-                    for h, n in zip(hash_rows, sizes)]
-        else:
-            cols = [self._scores(q) for q in queries]
-        s = np.stack(cols, axis=-1) if cols else \
-            np.zeros((self.num_records, 0), np.float32)
-        return threshold_hits_packed(s, threshold)
+    def _score_pairs(self, qp, cand_rec, cand_q):
+        hash_rows, sizes = qp
+        out = np.zeros(len(cand_rec), np.float32)
+        for g in np.unique(cand_q):
+            sel = np.nonzero(cand_q == g)[0]
+            out[sel] = self._scores_rows(
+                hash_rows[g], int(sizes[g]), rows=cand_rec[sel])
+        return out
 
     def save(self, path: str) -> None:
         np.savez_compressed(path, engine="kmv", seed=np.int64(self.seed),

@@ -160,13 +160,9 @@ def gbkmv_containment(
 BACKENDS = ("numpy", "jnp", "pallas")
 
 
-def normalize_backend(backend: str | None, impl: str | None = None) -> str:
-    """Resolve the public ``backend=`` option (``impl=`` is the deprecated
-    spelling used by older callers: "kernel" → "pallas")."""
-    if backend is None:
-        backend = {"kernel": "pallas", None: "jnp"}.get(impl, impl)
-    if backend == "kernel":
-        backend = "pallas"
+def normalize_backend(backend: str | None) -> str:
+    """Resolve the public ``backend=`` option (None → "jnp")."""
+    backend = "jnp" if backend is None else backend
     if backend not in BACKENDS:
         raise ValueError(f"backend must be one of {BACKENDS}, got {backend!r}")
     return backend
@@ -272,19 +268,13 @@ def containment_matrix(q, x, backend: str = "jnp", *, as_numpy: bool = True):
             q.values, q.thresh, q.buf, q.sizes)
         return np.asarray(out) if as_numpy else out
 
-    def one_query(qv, qt, qb, qs):
-        d_hat, _, _ = gkmv_pair_estimate(
-            qv, None, qt, x.values, x.lengths, x.thresh)
-        o1 = buffer_intersection(qb, x.buf)
-        return (o1.astype(jnp.float32) + d_hat) / jnp.maximum(
-            jnp.asarray(qs, jnp.float32), 1.0)
+    from repro.sketchindex.distributed import _scores_jnp
 
-    import jax
-
-    out = jax.vmap(one_query)(
+    out = _scores_jnp(
+        x.values, x.lengths, x.thresh, x.buf,
         jnp.asarray(q.values, jnp.uint32), jnp.asarray(q.thresh, jnp.uint32),
         jnp.asarray(q.buf, jnp.uint32), jnp.asarray(q.sizes, jnp.int32))
-    return np.asarray(out.T) if as_numpy else out.T
+    return np.asarray(out) if as_numpy else out
 
 
 # ---------------------------------------------------------------------------

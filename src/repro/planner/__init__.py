@@ -6,17 +6,22 @@ postings so selective queries stop sweeping the whole index.
                  under insert
     prune.py     threshold-aware candidate generation with per-block
                  header skipping + packed hits
-    plan.py      per-batch dense-vs-pruned cost decision + executor
-                 (+ pruned_topk: upper-bound-pruned top-k)
+    plan.py      per-batch dense-vs-pruned cost decision + the host
+                 filter-and-verify (+ pruned_topk: upper-bound-pruned
+                 top-k)
     device.py    device-resident pruned execution over a SketchArena
                  (block decode → gather-score → packed thresholding
                  with no host round-trip; imported lazily — jax-heavy)
+    route.py     the router: the one place a batch's route is decided
+                 and run, for ShardedIndex, the api sketch indexes and
+                 WindowManager alike (``serve``, ``fused``, ``Routed``;
+                 import it directly — it pulls in the arena)
 
 The ragged verify kernel lives with the other Pallas kernels in
 :mod:`repro.kernels.gather_score`, the device block-decode/merge in
-:mod:`repro.kernels.postings_merge`. ``repro.api`` threads ``plan=``
-("auto" | "dense" | "pruned") through every sketch engine's
-``query``/``batch_query``/``topk``.
+:mod:`repro.kernels.postings_merge`. ``plan=`` ("auto" | "dense" |
+"pruned") reaches the router from every sketch index's
+``query``/``batch_query``/``topk``/``serve_batch``.
 """
 
 from repro.planner.plan import (

@@ -71,19 +71,6 @@ def unpack_query_rows(qp):
     return hash_rows, bit_rows, np.asarray(qp.sizes)
 
 
-def gbkmv_plan_queries(core, queries):
-    """Sketch a query batch and unpack the planner's per-query inputs.
-
-    Shared by the host GB-KMV index and ShardedIndex (one definition, so
-    the two planners can't drift). Returns (query pack, retained-hash
-    rows, buffer-bit rows, query sizes).
-    """
-    from repro.sketchindex.distributed import batch_queries
-
-    qp = batch_queries(core, queries)
-    return (qp,) + unpack_query_rows(qp)
-
-
 def _probe(
     posts: PostingsIndex | Sequence[PostingsIndex],
     q_hash_rows: Sequence[np.ndarray],

@@ -28,19 +28,12 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import compat
-from repro.core.estimators import (buffer_intersection, gkmv_pair_estimate,
-                                   query_cols)
+from repro.core.estimators import buffer_intersection, gkmv_pair_estimate
 from repro.core.hashing import PAD
 from repro.core.sketches import PackedSketches
-from repro.obs.trace import stage as obs_stage, tally as obs_tally
 from repro.parallel.sharding import logical_to_spec
-
-
-#: Candidates per device scoring call of the mesh's pruned top-k. A call
-#: dispatches to every device and syncs with the host, so the host
-#: loop's small default chunk would make thousands of calls per batch at
-#: 480k records; scoring extra candidates never changes the answer.
-DEVICE_TOPK_CHUNK = 4096
+from repro.planner.plan import unpack_query_rows
+from repro.planner.route import Routed
 
 
 @dataclasses.dataclass
@@ -111,7 +104,8 @@ def batch_queries(index, queries) -> PackedSketches:
 
 
 def _scores_jnp(values, lengths, thresh, buf, q_values, q_thresh, q_buf, q_sizes):
-    """Pure-jnp scoring [Mshard, Gq] — the pjit/dry-run lowering path."""
+    """Pure-jnp scoring [Mshard, Gq]: the jitted sweep's body, and the jnp
+    backend of ``estimators.containment_matrix``."""
     def one_query(qv, qt, qb, qs):
         d_hat, _, _ = gkmv_pair_estimate(qv, None, qt, values, lengths, thresh)
         o1 = buffer_intersection(qb, buf)
@@ -194,17 +188,16 @@ def _score_pairs_jit(x_rows, x_thresh, x_buf, q: PackedSketches,
 
 
 def score_batch(didx: DeviceIndex, q: PackedSketches,
-                backend: str | None = None, impl: str | None = None):
+                backend: str | None = None):
     """Containment scores f32[Mp, Gq]; records sharded, queries replicated.
 
     ``backend`` ∈ {"numpy", "jnp", "pallas"} — the one option threaded
-    through every scoring layer (``impl=`` is the deprecated spelling;
-    "kernel" → "pallas"). "numpy" computes on host from fetched shards —
-    a debug/parity path, not a serving path.
+    through every scoring layer. "numpy" computes on host from fetched
+    shards — a debug/parity path, not a serving path.
     """
     from repro.core.estimators import containment_matrix, normalize_backend
 
-    backend = normalize_backend(backend, impl)
+    backend = normalize_backend(backend)
     if backend == "numpy":
         x = PackedSketches(
             values=np.asarray(didx.values), lengths=np.asarray(didx.lengths),
@@ -269,22 +262,25 @@ def distributed_topk(scores, k: int, mesh: Mesh):
 
 
 def distributed_search(didx: DeviceIndex, q: PackedSketches, threshold: float,
-                       backend: str | None = None, impl: str | None = None):
+                       backend: str | None = None):
     """Algorithm 2 at cluster scale: boolean candidate mask [Mp, Gq]."""
-    scores = score_batch(didx, q, backend=backend, impl=impl)
+    scores = score_batch(didx, q, backend=backend)
     return scores >= threshold, scores
 
 
-class ShardedIndex:
+class ShardedIndex(Routed):
     """Device-sharded GB-KMV index implementing the ``repro.api`` protocol.
 
     Wraps a host :class:`GBKMVIndex` placed on a mesh (``to_device_index``)
     so serving, benchmarks, and the api registry talk to sharded and host
-    indexes through the same surface — ``SketchServer`` no longer
-    special-cases device placement.
+    indexes through the same surface. Every query answers through the
+    router (:mod:`repro.planner.route`); this class says only how a mesh
+    scores: the sharded sweep, ``distributed_topk``, and pair scoring on
+    the devices that hold each record.
     """
 
     engine = "gbkmv"
+    _device_prunable = True
 
     def __init__(self, index, mesh: Mesh, backend: str = "jnp",
                  budget: int | None = None):
@@ -299,34 +295,50 @@ class ShardedIndex:
             index, "budget", None)
         self.didx = to_device_index(core, mesh)
         self._lane_rows = None
-        self.last_plan = None
-        # Explain/observability bookkeeping from the most recent planned
-        # batch: per-query CandidateSets (pruned path only) and the
-        # planner inputs needed for upper-bound stats.
-        self.last_candidates = None
-        self._last_plan_inputs = None
 
     @property
     def num_records(self) -> int:
         return self.host.num_records
 
-    # -- planner plumbing: per-shard postings, candidates unioned --
+    @property
+    def _mesh_devices(self) -> int:
+        return self.mesh.devices.size
+
+    # -- how a mesh scores (the router's hooks) --
+    def _sketch_pack(self):
+        return self.host.sketches
+
+    def _sketch(self, queries) -> PackedSketches:
+        return batch_queries(self.host, queries)
+
+    def _query_rows(self, qp):
+        return unpack_query_rows(qp)
+
     def _shard_postings(self):
         """(postings, row_offsets) over the arena's record slices.
 
         One block-compressed postings index per record-offset slice,
         built from column *views* of the shared arena (no per-shard
-        host copies) and cached on the arena itself — so the host api
-        index and the sharded view maintain ONE postings store.
-        Candidate generation probes every slice (block headers first —
-        skipping applies per shard) and unions the (disjoint) results —
-        the host-side mirror of the mesh's all_gather. After inserts
-        the slices update in place (τ-truncation of each slice's blocks
-        + re-encoding only the appended rows); their boundaries may
-        then lag the mesh's ceil-partition, which is harmless because
-        the union reports global record ids either way.
+        host copies) and cached on the arena itself. Candidate
+        generation probes every slice (block headers first — skipping
+        applies per shard) and unions the (disjoint) results — the
+        host-side mirror of the mesh's all_gather. After inserts the
+        slices update in place (τ-truncation of each slice's blocks +
+        re-encoding only the appended rows); their boundaries may then
+        lag the mesh's ceil-partition, which is harmless because the
+        union reports global record ids either way.
         """
         return self.host.sketches.shard_postings(self.mesh.devices.size)
+
+    def _dense_scores(self, qp: PackedSketches):
+        return score_batch(self.didx, qp, backend=self.backend)
+
+    def _dense_topk(self, scores, k: int):
+        """Per-shard ``lax.top_k`` + one all_gather; ties break lower id
+        first, the order every pruned route produces."""
+        vals, ids = distributed_topk(scores, k, self.mesh)
+        jax.block_until_ready(vals)
+        return list(zip(np.asarray(ids).astype(np.int64), np.asarray(vals)))
 
     def _score_pairs(self, qp, cand_rec, cand_q) -> np.ndarray:
         """f32[P] pair scores for the host merge's candidates. A device
@@ -377,265 +389,10 @@ class ShardedIndex:
                 lane_rows(self.didx.values), self.didx.values.sharding)
         return self._lane_rows
 
-    def _device_route(self) -> bool:
-        """True when the fused all-device pipeline can serve this index:
-        a single-device mesh (the fused program is unsharded) plus a
-        device scoring backend. Multi-device meshes keep the per-shard
-        host merge — its block skipping applies shard by shard."""
-        from repro.core.arena import SketchArena
-
-        return (self.mesh.devices.size == 1
-                and self.backend in ("jnp", "pallas")
-                and isinstance(self.host.sketches, SketchArena))
-
-    def _pruned_batch(self, queries, thresholds, plan: str):
-        """Planner route for a batch. Returns (hits, qp): hits is None
-        when the cost model (or a guard) sends the batch dense, and qp
-        is the already-sketched query pack (or None) so the dense path
-        never re-sketches the batch."""
-        from repro import planner
-        from repro.planner.plan import gbkmv_plan_queries
-
-        plan = planner.normalize_plan(plan)
-        thr = np.asarray(thresholds, np.float64)
-        t_min = float(thr.min()) if thr.size else 0.0
-        self.last_candidates = None
-        self._last_plan_inputs = None
-        if plan == "dense" or t_min <= 0.0 or not queries:
-            # A decision was still made — record it so explain and the
-            # drift gauge always have something to read.
-            self.last_plan = planner.QueryPlan(
-                "dense", np.nan, np.nan, 0,
-                "forced" if plan == "dense" else "threshold <= 0")
-            return None, None
-        with obs_stage("serve.sketch", queries=len(queries)):
-            qp, hash_rows, bit_rows, sizes = gbkmv_plan_queries(
-                self.host, queries)
-        with obs_stage("shard.postings", shards=self.mesh.devices.size):
-            posts, offs = self._shard_postings()
-        s: PackedSketches = self.host.sketches
-        decision = planner.choose_plan(
-            posts, hash_rows, bit_rows, t_min,
-            s.num_records, s.capacity, plan=plan)
-        self.last_plan = decision
-        self._last_plan_inputs = (hash_rows, sizes, posts)
-        # What the probe saw, as the cost model was fed it: count =
-        # planner-routed batches, sum = blocks (entries).
-        obs_tally("probe.tail_blocks", decision.tail_blocks)
-        obs_tally("probe.dense_blocks", decision.tail_dense_blocks)
-        obs_tally("probe.buf_blocks", decision.blocks - decision.tail_blocks)
-        obs_tally("probe.entries", decision.hits)
-        if decision.path == "dense":
-            return None, qp
-
-        if self._device_route():
-            from repro.planner import device as planner_device
-
-            # Fused probe→decode→score→threshold entirely on device:
-            # per-query candidate sets never materialize on host, so
-            # explain carries the probe breakdown only.
-            ids = planner_device.pruned_batch_device(
-                self.host.sketches, qp, thresholds,
-                plan=decision, backend=self.backend)
-            return ids, qp
-
-        ids, cands = planner.pruned_batch(
-            posts, hash_rows, bit_rows, sizes, thresholds,
-            functools.partial(self._score_pairs, qp), row_offsets=offs)
-        self.last_candidates = cands
-        return ids, qp
-
-    # -- scoring --
-    def _dense_scores(self, qp: PackedSketches):
-        """The dense sweep of a served batch. One tally per sweep: count =
-        sweeps, sum = the query columns its K∩ compare reads (the jnp
-        sweep stops past the widest live row; the others read them all)."""
-        obs_tally("sweep.query_cols",
-                  query_cols(qp.lengths) if self.backend == "jnp"
-                  else qp.values.shape[1])
-        return score_batch(self.didx, qp, backend=self.backend)
-
     def batch_scores(self, queries) -> np.ndarray:
         """f32[m, Gq] (padding rows trimmed) — one sharded index sweep."""
-        qp = batch_queries(self.host, [np.asarray(q) for q in queries])
-        s = score_batch(self.didx, qp, backend=self.backend)
-        return np.asarray(s)[: self.num_records]
-
-    def _serve_explains(self, hits, thr, t0) -> list[dict]:
-        """Per-query explain dicts for the batch just served, built from
-        the planner bookkeeping ``_pruned_batch`` left behind."""
-        import time
-
-        from repro import obs
-
-        hash_rows, sizes, posts = self._last_plan_inputs or (None, None, None)
-        ex = obs.build_explain(
-            self.last_plan, engine=self.engine, backend=self.backend,
-            n_queries=len(hits), hits=hits, cands=self.last_candidates,
-            hash_rows=hash_rows, sizes=sizes, posts=posts,
-            measured_seconds=time.perf_counter() - t0)
-        for g, e in enumerate(ex):
-            e["threshold"] = float(thr[g])
-        return ex
-
-    def serve_batch(self, queries, thresholds, k: int, plan: str = "auto",
-                    explain: bool = False):
-        """One sweep answering threshold + top-k for a whole batch.
-
-        ``thresholds`` is scalar or per-query. Returns one dict per query:
-        {"hits", "topk_ids", "topk_scores"}. ``plan`` routes both halves:
-        threshold hits through the pruned filter-and-verify and — when
-        forced "pruned" — top-k through the planner-aware upper-bound
-        pruning as well. ``plan="auto"`` keeps top-k on the dense sweep
-        (the batch amortizes it and the hit masks fall out of the same
-        scores), matching it bit for bit. With ``explain=True`` each
-        dict gains an ``"explain"`` entry (:mod:`repro.obs.explain`).
-        """
-        import time
-
-        from repro.planner.prune import threshold_hits_packed
-
-        t0 = time.perf_counter()
-        queries = [np.asarray(q) for q in queries]
-        thr = np.broadcast_to(np.asarray(thresholds, np.float64),
-                              (len(queries),))
-        empty_ids = np.zeros(0, np.int64)
-        empty_scores = np.zeros(0, np.float32)
-        if k <= 0 or plan == "pruned":
-            hits, qp = self._pruned_batch(queries, thr, plan)
-            route = ("dense" if hits is None else
-                     "fused" if self._device_route() else "host_pruned")
-            # One count per served batch: count = batches, sum = queries.
-            obs_tally(f"route.{route}", len(queries))
-            if hits is None:
-                if qp is None:
-                    with obs_stage("serve.sketch", queries=len(queries)):
-                        qp = batch_queries(self.host, queries)
-                with obs_stage("serve.score", queries=len(queries)) as span:
-                    scores = span.sync(self._dense_scores(qp))
-                with obs_stage("serve.hits"):
-                    hits = threshold_hits_packed(
-                        scores[: self.num_records], thr)
-            if k <= 0:
-                out = [{"hits": h, "topk_ids": empty_ids,
-                        "topk_scores": empty_scores} for h in hits]
-                if explain:
-                    for res, e in zip(out, self._serve_explains(
-                            hits, thr, t0)):
-                        res["explain"] = e
-                return out
-            # Reuse the batch's query pack: one sketching pass serves
-            # both the threshold hits and every pruned top-k.
-            ex = self._serve_explains(hits, thr, t0) if explain else None
-            with obs_stage("serve.topk", k=k):
-                tops = self._pruned_topk_batch(queries, k, qp=qp)
-            out = [{"hits": h, "topk_ids": t[0], "topk_scores": t[1]}
-                   for h, t in zip(hits, tops)]
-            if ex is not None:
-                for res, e in zip(out, ex):
-                    res["explain"] = e
-            return out
-
-        # Dense sweep route (top-k batches on plan="auto"): the planner
-        # is never consulted, but a routing decision still happened —
-        # record it so explain/drift always have the current batch.
-        from repro import planner
-        from repro.core import cost_model
-
-        s = self.host.sketches
-        self.last_candidates = None
-        self._last_plan_inputs = None
-        self.last_plan = planner.QueryPlan(
-            "dense", cost_model.dense_sweep_cost(
-                s.num_records, s.capacity, len(queries)), np.nan, 0,
-            "topk batch: dense sweep amortized")
-        obs_tally("route.dense", len(queries))
-        with obs_stage("serve.sketch", queries=len(queries)):
-            qp = batch_queries(self.host, queries)
-        with obs_stage("serve.score", queries=len(queries)):
-            scores = self._dense_scores(qp)
-        with obs_stage("serve.topk", k=k):
-            vals, ids = distributed_topk(scores, k, self.mesh)
-            jax.block_until_ready(vals)
-        with obs_stage("serve.hits"):
-            hits = threshold_hits_packed(scores[: self.num_records], thr)
-        out = [
-            {"hits": hits[j],
-             "topk_ids": np.asarray(ids)[j],
-             "topk_scores": np.asarray(vals)[j]}
-            for j in range(len(queries))
-        ]
-        if explain:
-            for res, e in zip(out, self._serve_explains(hits, thr, t0)):
-                res["explain"] = e
-        return out
-
-    # -- repro.api protocol --
-    def query(self, q_ids, threshold: float, *, plan: str = "auto") -> np.ndarray:
-        return self.batch_query([q_ids], threshold, plan=plan)[0]
-
-    def batch_query(self, queries, threshold: float, *,
-                    plan: str = "auto") -> list[np.ndarray]:
-        from repro import planner
-
-        plan = planner.normalize_plan(plan)
-        queries = [np.asarray(q) for q in queries]
-        if not queries:
-            return []
-        hits, qp = self._pruned_batch(queries, float(threshold), plan)
-        if hits is not None:
-            return hits
-        if qp is None:
-            qp = batch_queries(self.host, queries)
-        s = score_batch(self.didx, qp, backend=self.backend)
-        return planner.threshold_hits_packed(s[: self.num_records], threshold)
-
-    def _pruned_topk_batch(self, queries, k: int, qp=None):
-        """Planner-aware top-k for a whole batch over ONE query pack
-        (``qp`` reuses a pack the caller already sketched)."""
-        from repro import planner
-        from repro.planner.plan import unpack_query_rows
-
-        if qp is None:
-            qp = batch_queries(self.host, queries)
-        if self._device_route():
-            from repro.planner import device as planner_device
-
-            return planner_device.pruned_topk_device(
-                self.host.sketches, qp, k, backend=self.backend)
-        hash_rows, bit_rows, sizes = unpack_query_rows(qp)
-        posts, offs = self._shard_postings()
-        s: PackedSketches = self.host.sketches
-        out = []
-        for g in range(len(queries)):
-            def score_fn(cand_rec, _cand_q, g=g):
-                return self._score_pairs(
-                    qp, cand_rec, np.full(len(cand_rec), g, np.int32))
-
-            out.append(planner.pruned_topk(
-                posts, hash_rows[g], bit_rows[g], int(sizes[g]), k,
-                score_fn, s.num_records, row_offsets=offs,
-                chunk=None if self.backend == "numpy" else
-                DEVICE_TOPK_CHUNK))
-        return out
-
-    def topk(self, q_ids, k: int, *, plan: str = "auto"):
-        """Global top-k. ``plan="pruned"`` routes through the planner's
-        postings-driven upper-bound pruning (host merge over the shard
-        slices + device gather-scoring) with exact parity against the
-        dense mesh sweep; "auto"/"dense" run the sharded sweep +
-        all_gather (``lax.top_k`` breaks ties lower-id-first, the same
-        deterministic order the pruned path produces)."""
-        from repro import planner
-
-        plan = planner.normalize_plan(plan)
-        if plan == "pruned" and k > 0:
-            return self._pruned_topk_batch([np.asarray(q_ids)], k)[0]
-        qp = batch_queries(self.host, [np.asarray(q_ids)])
-        scores = score_batch(self.didx, qp, backend=self.backend)
-        vals, ids = distributed_topk(scores, k, self.mesh)
-        return (np.asarray(ids)[0].astype(np.int64),
-                np.asarray(vals)[0].astype(np.float32))
+        qp = self._sketch([np.asarray(q) for q in queries])
+        return np.asarray(self._dense_scores(qp))[: self.num_records]
 
     def insert(self, new_records, budget: int | None = None):
         """Dynamic insert on the host sketch (delegated to the api index so

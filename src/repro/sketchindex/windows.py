@@ -324,38 +324,13 @@ class WindowManager:
 
     def serve_batch(self, queries, thresholds, k: int, plan: str = "auto",
                     explain: bool = False):
-        """One sweep answering threshold + top-k for a whole batch over
-        every live epoch — the ``AsyncSketchServer`` execution protocol
-        (same result shape as ``ShardedIndex.serve_batch``): one dict
-        per query with "hits", "topk_ids", "topk_scores" (+ "explain").
-        """
+        """Threshold hits and top-k for a whole batch over every live
+        epoch — the ``AsyncSketchServer`` execution protocol, answered by
+        the current view's ``serve_batch`` (one route per batch)."""
         idx = self.index()
-        queries = [np.asarray(q) for q in queries]
-        n = len(queries)
-        thr = np.broadcast_to(np.asarray(thresholds, np.float64), (n,))
-        hits: list = [None] * n
-        exs: list = [None] * n
-        for t in np.unique(thr):
-            sel = np.nonzero(thr == t)[0]
-            sub = [queries[i] for i in sel]
-            if explain:
-                h, e = idx.batch_query(sub, float(t), plan=plan,
-                                       explain=True)
-                for i, j in enumerate(sel):
-                    exs[j] = e[i]
-            else:
-                h = idx.batch_query(sub, float(t), plan=plan)
-            for i, j in enumerate(sel):
-                hits[j] = h[i]
+        out = idx.serve_batch(queries, thresholds, k, plan=plan,
+                              explain=explain)
         self.last_plan = idx.last_plan
-        empty = (np.zeros(0, np.int64), np.zeros(0, np.float32))
-        tops = [idx.topk(q, k, plan=plan) if k > 0 else empty
-                for q in queries]
-        out = [{"hits": h, "topk_ids": t[0], "topk_scores": t[1]}
-               for h, t in zip(hits, tops)]
-        if explain:
-            for res, e in zip(out, exs):
-                res["explain"] = e
         return out
 
     # -- observability -----------------------------------------------------

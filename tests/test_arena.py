@@ -8,6 +8,7 @@ path (transfer-guarded), and pruned-vs-dense top-k parity.
 """
 
 import dataclasses
+import functools
 
 import jax
 import numpy as np
@@ -54,7 +55,7 @@ def test_postings_shared_between_host_and_sharded(gb_index):
     from repro.sketchindex import ShardedIndex
 
     arena = gb_index._sketch_pack()
-    post = gb_index._postings()
+    post = gb_index._sketch_pack().postings()
     assert arena._post is post                    # owned by the arena
     mesh = jax.make_mesh((1, 1), ("data", "model"))
     sh = ShardedIndex(gb_index, mesh)
@@ -139,8 +140,9 @@ def test_arena_save_load_roundtrip_with_postings(corpus, tmp_path, engine):
     idx.save(path)
     loaded = api.load_index(path)
     # Postings travel with the arena: no rebuild on first pruned query.
-    assert loaded._post is not None
-    assert planner.postings_equal(loaded._post, idx._post)
+    assert loaded._sketch_pack()._post is not None
+    assert planner.postings_equal(loaded._sketch_pack()._post,
+                                  idx._sketch_pack()._post)
     for t in (0.4, 0.8):
         for a, b in zip(idx.batch_query(queries, t),
                         loaded.batch_query(queries, t)):
@@ -174,7 +176,7 @@ def test_v2_flat_postings_npz_still_loads(corpus, tmp_path, gb_index):
     gb_index.batch_query(queries, 0.6, plan="pruned")   # build postings
     core = gb_index.core
     s = core.sketches
-    post = gb_index._post
+    post = gb_index._sketch_pack()._post
     path = str(tmp_path / "v2_flat.npz")
     np.savez_compressed(                    # the exact v2 field set
         path, engine="gbkmv", tau=np.uint32(core.tau),
@@ -188,8 +190,9 @@ def test_v2_flat_postings_npz_still_loads(corpus, tmp_path, gb_index):
         post_rec_ids=post.rec_ids, post_buf_offsets=post.buf_offsets,
         post_buf_rec_ids=post.buf_rec_ids, post_tau=np.uint32(post.tau))
     loaded = api.load_index(path)
-    assert loaded._post is not None         # postings traveled, re-encoded
-    assert planner.postings_equal(loaded._post, post)
+    # postings traveled, re-encoded
+    assert loaded._sketch_pack()._post is not None
+    assert planner.postings_equal(loaded._sketch_pack()._post, post)
     for t in (0.4, 0.8):
         for a, b in zip(gb_index.batch_query(queries, t),
                         loaded.batch_query(queries, t, plan="pruned")):
@@ -212,7 +215,8 @@ def test_legacy_packed_npz_still_loads(corpus, tmp_path, gb_index):
         sizes=np.asarray(s.sizes))
     loaded = api.load_index(path)
     assert isinstance(loaded._sketch_pack(), SketchArena)
-    assert loaded._post is None             # postings lazy, not persisted
+    # postings lazy, not persisted
+    assert loaded._sketch_pack()._post is None
     for a, b in zip(gb_index.batch_query(queries, 0.6),
                     loaded.batch_query(queries, 0.6)):
         np.testing.assert_array_equal(a, b)
@@ -313,8 +317,9 @@ def test_pruned_topk_small_chunks_early_stop(gb_index, corpus):
     for k in (1, 3, 10):
         want = gb_index.topk(q, k, plan="dense")
         got = planner.pruned_topk(
-            gb_index._postings(), hash_rows[0], bit_rows[0], int(sizes[0]),
-            k, gb_index._pair_score_fn(qp), gb_index.num_records, chunk=4)
+            gb_index._sketch_pack().postings(), hash_rows[0], bit_rows[0],
+            int(sizes[0]), k, functools.partial(gb_index._score_pairs, qp),
+            gb_index.num_records, chunk=4)
         np.testing.assert_array_equal(want[0], got[0])
         np.testing.assert_array_equal(want[1], got[1])
 
@@ -335,7 +340,7 @@ def test_topk_deterministic_tie_break():
 def test_pruned_topk_after_insert(corpus):
     recs, total, queries = corpus
     idx = api.get_engine("gbkmv").build(recs, int(total * 0.06))
-    idx._postings()
+    idx._sketch_pack().postings()
     extra = generate_dataset(m=30, n_elems=3000, alpha_freq=1.0,
                              alpha_size=1.6, seed=14)
     idx.insert(extra)

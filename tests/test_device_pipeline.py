@@ -10,6 +10,7 @@ staging buffers, the compile/staging counters, and the fused device
 *build* (postings encoded on device, bit-identical to the host encoder).
 """
 
+import functools
 import logging
 
 import jax
@@ -21,6 +22,7 @@ from repro.core.arena import SketchArena
 from repro.data.synth import generate_dataset, make_query_workload
 from repro.planner import device as planner_device
 from repro.planner import postings as postings_mod
+from repro.planner import route
 from repro.planner.prune import f32_threshold
 
 DEVICE_BACKENDS = ("jnp", "pallas")
@@ -197,8 +199,9 @@ def test_topk_matches_host_pruned_topk(corpus, engine, backend):
             # single-query pack: pruned_topk's score_fn addresses query 0
             qp_g, hr, br, sz = idx._plan_queries([queries[g]])
             want_ids, want_vals = planner.pruned_topk(
-                idx._postings(), hr[0], br[0], int(sz[0]),
-                k, idx._pair_score_fn(qp_g), arena.num_records)
+                idx._sketch_pack().postings(), hr[0], br[0], int(sz[0]),
+                k, functools.partial(idx._score_pairs, qp_g),
+                arena.num_records)
             np.testing.assert_array_equal(ids, want_ids)
             np.testing.assert_array_equal(vals, want_vals)
 
@@ -342,7 +345,7 @@ def test_sharded_single_device_takes_fused_route(corpus):
     dense = build("gbkmv", recs, int(total * 0.1), backend="numpy")
     mesh = Mesh(np.array(jax.devices()[:1]), ("records",))
     sh = ShardedIndex(host, mesh, backend="jnp")
-    assert sh._device_route()
+    assert route.fused(sh)
     got = sh.batch_query(queries, 0.6, plan="pruned")
     want = dense.batch_query(queries, 0.6, plan="dense")
     for w, g in zip(want, got):

@@ -304,7 +304,7 @@ def test_explain_pruned_matches_planner_internals(explain_setup, backend):
     # Recompute the planner's internals independently and require the
     # explain numbers to match them exactly.
     _, hash_rows, bit_rows, q_sizes = idx._plan_queries(queries)
-    post = idx._postings()
+    post = idx._sketch_pack().postings()
     probe = probe_hits_per_query(post, hash_rows, bit_rows)
     for g, e in enumerate(ex):
         assert e["plan"] == "pruned"

@@ -73,23 +73,23 @@ def test_postings_buffer_rows(gb_index):
 def test_incremental_update_equals_rebuild(corpus, gb_index):
     recs, total, _ = corpus
     idx = api.get_engine("gbkmv").build(recs, int(total * 0.06))
-    idx._postings()                          # build before the insert
+    idx._sketch_pack().postings()            # build before the insert
     extra = generate_dataset(m=50, n_elems=4000, alpha_freq=1.0,
                              alpha_size=1.6, seed=7)
     idx.insert(extra)
     assert idx.stats.tau_retightens >= 1     # deletion path exercised
     fresh = planner.build_postings(idx.core.sketches)
-    assert planner.postings_equal(idx._post, fresh)
+    assert planner.postings_equal(idx._sketch_pack()._post, fresh)
 
 
 def test_incremental_update_without_retighten(corpus):
     recs, total, _ = corpus
     idx = api.get_engine("gbkmv").build(recs, int(total * 10))  # roomy budget
-    idx._postings()
+    idx._sketch_pack().postings()
     idx.insert([np.asarray([1, 2, 3]), np.asarray([4, 5])])
     assert idx.stats.tau_retightens == 0     # append-only path
     assert planner.postings_equal(
-        idx._post, planner.build_postings(idx.core.sketches))
+        idx._sketch_pack()._post, planner.build_postings(idx.core.sketches))
 
 
 # ---------------------------------------------------------------------------
@@ -127,7 +127,7 @@ def test_parity_after_insert_retighten(corpus, backend):
     recs, total, queries = corpus
     idx = api.get_engine("gbkmv").build(recs, int(total * 0.06),
                                         backend=backend)
-    idx._postings()
+    idx._sketch_pack().postings()
     extra = generate_dataset(m=40, n_elems=4000, alpha_freq=1.0,
                              alpha_size=1.6, seed=9)
     idx.insert(extra)
@@ -143,7 +143,7 @@ def test_candidates_never_drop_a_hit(corpus, gb_index):
     """The filter step alone (before verify) is a superset of the dense
     hits — pruning never drops a record with estimated containment ≥ t."""
     recs, total, queries = corpus
-    post = gb_index._postings()
+    post = gb_index._sketch_pack().postings()
     for t in (0.2, 0.5, 0.8):
         _, hash_rows, bit_rows, sizes = gb_index._plan_queries(
             [np.asarray(q) for q in queries])
@@ -182,7 +182,7 @@ def test_plan_guards_and_forcing(corpus, gb_index):
     recs, total, queries = corpus
     _, hash_rows, bit_rows, _ = gb_index._plan_queries(
         [np.asarray(q) for q in queries[:2]])
-    post = gb_index._postings()
+    post = gb_index._sketch_pack().postings()
     s = gb_index.core.sketches
     # t <= 0: pruning is unsound, always dense (even when forced).
     d = planner.choose_plan(post, hash_rows, bit_rows, 0.0,
@@ -290,7 +290,7 @@ def test_sharded_planner_matches_dense(corpus, gb_index):
 
 def test_shard_union_equals_global(gb_index):
     """Cross-shard candidate union == single global postings' candidates."""
-    post_global = gb_index._postings()
+    post_global = gb_index._sketch_pack().postings()
     s = gb_index.core.sketches
     qp, hash_rows, bit_rows, sizes = gb_index._plan_queries(
         [np.arange(10), np.arange(50, 90)])

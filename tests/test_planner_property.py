@@ -31,7 +31,7 @@ def test_pruning_never_drops_a_qualifying_record(recs, q, frac, t):
     scores = idx.scores(q)                       # dense estimator, f32[m]
     qualifying = np.nonzero(scores >= t)[0]
 
-    post = idx._postings()
+    post = idx._sketch_pack().postings()
     _, hash_rows, bit_rows, sizes = idx._plan_queries([q])
     cand = prune.candidates_for(post, hash_rows[0], bit_rows[0], float(t),
                                 int(sizes[0]))
@@ -49,10 +49,10 @@ def test_postings_maintenance_preserves_parity(recs, extra, q, t):
     extra = [np.asarray(sorted(r)) for r in extra]
     total = sum(len(r) for r in recs)
     idx = api.get_engine("gbkmv").build(recs, max(int(total * 0.3), len(recs)))
-    idx._postings()                              # force incremental path
+    idx._sketch_pack().postings()                # force incremental path
     idx.insert(extra)
     assert planner.postings_equal(
-        idx._post, planner.build_postings(idx.core.sketches))
+        idx._sketch_pack()._post, planner.build_postings(idx.core.sketches))
     q = np.asarray(sorted(q))
     np.testing.assert_array_equal(
         idx.query(q, float(t), plan="pruned"),

@@ -256,7 +256,7 @@ def test_device_dense_block_overlay_parity(backend):
             for i in range(1200)]
     idx = api.get_engine("gbkmv").build(recs, budget=5000, backend=backend,
                                         r=0)
-    kind = (idx._postings().tail.meta >> np.uint32(13)) & 1
+    kind = (idx._sketch_pack().postings().tail.meta >> np.uint32(13)) & 1
     assert int(kind.sum()) > 0                 # dense blocks really exist
     q = np.asarray([7, 99991, 99992])
     dense = idx.batch_query([q], 0.2, plan="dense")[0]

@@ -50,9 +50,6 @@ def test_kernel_impl_matches_jnp():
     s_np = np.asarray(score_batch(didx, qp, backend="numpy"))
     np.testing.assert_allclose(s_krn, s_jnp, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(s_np, s_jnp, rtol=1e-5, atol=1e-5)
-    # deprecated spelling still routes: impl="kernel" → backend="pallas"
-    s_old = np.asarray(score_batch(didx, qp, impl="kernel"))
-    np.testing.assert_allclose(s_old, s_krn, rtol=0, atol=0)
 
 
 def test_distributed_topk_matches_numpy():

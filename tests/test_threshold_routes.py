@@ -137,8 +137,7 @@ def test_each_batch_counts_its_route_once(corpus, served):
             continue
         # The probe's counts, recomputed apart from the served flush.
         sharded = srv.index
-        _, hash_rows, bit_rows, _ = planner_plan.gbkmv_plan_queries(
-            sharded.host, queries)
+        _, hash_rows, bit_rows, _ = sharded._plan_queries(queries)
         posts, _ = sharded._shard_postings()
         tb, td, bb = planner_plan.probe_block_stats(posts, hash_rows,
                                                     bit_rows)
@@ -240,3 +239,38 @@ def test_topk_sweeps_tally_their_query_columns(corpus):
             for qs in batches]
     assert cols[0] > 0
     assert prof.tallies()["sweep.query_cols"] == (2, float(sum(cols)))
+
+
+@pytest.fixture(scope="module")
+def doors(corpus):
+    """The three serving doors over the same records: a ShardedIndex on a
+    1x1 mesh, the api GB-KMV index itself, and a WindowManager holding
+    the records in one epoch."""
+    from repro import api
+
+    recs, index, budget, _, _ = corpus
+    windowed = api.get_engine("gbkmv").build(recs, budget, r="auto", seed=0,
+                                             backend="jnp", windowed=True)
+    return {"sharded": _sharded(index), "api": index, "windowed": windowed}
+
+
+@pytest.mark.parametrize("k", [0, 5])
+@pytest.mark.parametrize("plan", ["auto", "dense", "pruned"])
+def test_every_door_routes_and_answers_alike(corpus, doors, plan, k):
+    """Per-query thresholds mixed over 0.5/0.7/0.9: every door takes the
+    same route and gives the same hits, top-k ids and top-k scores."""
+    _, _, _, queries, thr = corpus
+    served = {name: door.serve_batch(queries, thr, k, plan=plan)
+              for name, door in doors.items()}
+    paths = {name: door.last_plan.path for name, door in doors.items()}
+    assert len(set(paths.values())) == 1, paths
+    if plan != "auto":
+        assert paths["api"] == plan
+    want = served["sharded"]
+    assert sum(len(r["hits"]) for r in want) > 0
+    assert all(len(r["topk_ids"]) == k for r in want)
+    for name, out in served.items():
+        for w, g in zip(want, out):
+            for key in ("hits", "topk_ids", "topk_scores"):
+                np.testing.assert_array_equal(g[key], w[key],
+                                              err_msg=f"{name} {key}")
