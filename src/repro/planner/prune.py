@@ -221,21 +221,26 @@ def mask_to_hits(mask: np.ndarray) -> list[np.ndarray]:
     return np.split(rec_idx.astype(np.int64), np.cumsum(counts)[:-1])
 
 
+def threshold_mask(scores, thresholds) -> np.ndarray:
+    """bool[m, Gq] host mask of ``scores >= thresholds``, the comparison
+    run where the scores live (device-side for jnp: only the bool mask
+    crosses to host, 4× less transfer than the float matrix).
+    ``thresholds`` is scalar or per-query."""
+    thr = f32_threshold(thresholds)
+    if isinstance(scores, np.ndarray):
+        return scores >= (thr if thr.ndim == 0 else thr[None, :])
+    import jax.numpy as jnp
+
+    return np.asarray(scores >= (jnp.float32(thr) if thr.ndim == 0
+                                 else jnp.asarray(thr, jnp.float32)[None, :]))
+
+
 def threshold_hits_packed(scores, thresholds) -> list[np.ndarray]:
     """Per-query hit ids from a score matrix, comparison at the source.
 
     ``scores`` is f32[m, Gq] — numpy OR a device (jnp) array. The ≥
-    comparison runs where the scores live (device-side for jnp: only the
-    bool mask crosses to host, 4× less transfer than the float matrix),
-    then one vectorized nonzero pass packs all queries' indices.
-    ``thresholds`` is scalar or per-query.
+    comparison is :func:`threshold_mask`'s, then one vectorized nonzero
+    pass packs all queries' indices. ``thresholds`` is scalar or
+    per-query.
     """
-    thr = f32_threshold(thresholds)
-    if isinstance(scores, np.ndarray):
-        mask = scores >= (thr if thr.ndim == 0 else thr[None, :])
-    else:
-        import jax.numpy as jnp
-
-        mask = scores >= (jnp.float32(thr) if thr.ndim == 0
-                          else jnp.asarray(thr, jnp.float32)[None, :])
-    return mask_to_hits(np.asarray(mask))
+    return mask_to_hits(threshold_mask(scores, thresholds))

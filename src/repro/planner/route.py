@@ -15,8 +15,8 @@ The decision rule, in :func:`serve` only:
         "dense" or the smallest threshold is <= 0; otherwise
         ``choose_plan`` on the postings probe. A pruned decision runs
         fused when :func:`fused` holds, host_pruned otherwise.
-    top-k: the dense sweep (its hit masks fall out of the same scores),
-        unless the plan is "pruned".
+    top-k: the dense sweep (finite thresholds' hit masks fall out of the
+        same scores), unless the plan is "pruned".
 
 ``ShardedIndex``, the api sketch indexes and ``WindowManager`` (through
 its current view) all answer through :func:`serve`. Each index says only
@@ -37,7 +37,8 @@ from repro.core.estimators import query_cols
 from repro.obs.trace import stage, tally
 from repro.planner.plan import (QueryPlan, choose_plan, merged_candidates,
                                 normalize_plan, pruned_batch, pruned_topk)
-from repro.planner.prune import threshold_hits_packed
+from repro.planner.prune import (f32_threshold, mask_to_hits,
+                                 threshold_hits_packed, threshold_mask)
 
 #: Candidates per scoring call of a device backend's host-pruned top-k. A
 #: call dispatches to every device and syncs with the host, so the host
@@ -127,7 +128,7 @@ def serve(index, queries, thresholds, k: int, plan: str = "auto",
                 tops = index._dense_topk(scores, k)
         if thr is not None:
             with stage("serve.hits"):
-                hits = threshold_hits_packed(scores[:m], thr)
+                hits = _dense_hits(scores, m, thr)
     elif thr is not None and route == "fused":
         from repro.planner import device as planner_device
 
@@ -170,6 +171,28 @@ def serve(index, queries, thresholds, k: int, plan: str = "auto",
             res["explain"] = e
         index.last_explain = ex
     return out
+
+
+def _dense_hits(scores, m: int, thr) -> list:
+    """Per-query hit ids from the sweep's scores f32[>= m, Gq], the mask
+    built only over the live columns: those whose float32 threshold is
+    below +inf. Every dense score is finite (the estimator clamps its
+    divisors), so a column at +inf has no hits, as on the pruned routes:
+    every top-k rider's column."""
+    thr32 = f32_threshold(thr)
+    live = thr32 < np.inf
+    # count = dense batches with thresholds, sum = columns masked
+    tally("serve.hits_cols", int(live.sum()))
+    if live.all():
+        return threshold_hits_packed(scores[:m], thr32)
+    hits = [np.zeros(0, np.int64) for _ in range(len(thr32))]
+    if live.any():
+        # The compare keeps the warmed [m, Gq] shape; the host packs
+        # only the live columns.
+        mask = threshold_mask(scores[:m], thr32)
+        for g, h in zip(np.flatnonzero(live), mask_to_hits(mask[:, live])):
+            hits[g] = h
+    return hits
 
 
 def _pruned_topk(index, qp, rows, k: int) -> list[tuple]:

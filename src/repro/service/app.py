@@ -387,8 +387,9 @@ class ServiceApp:
 
         # Route, probe and sweep tallies of the served path (obs.tally
         # into the server's profiler): which route served each read batch,
-        # what the planner's probe counted for the batches it routed, and
-        # the query columns each dense sweep compared.
+        # what the planner's probe counted for the batches it routed, the
+        # query columns each dense sweep compared and the columns whose hit
+        # mask it built.
         if srv.profiler is not None:
             def _tally(name, field):
                 def fn():
@@ -423,6 +424,11 @@ class ServiceApp:
                              help="Query columns the dense sweeps' K∩ compare "
                                   "read: each batch's widest live query row, "
                                   "rounded up to the compare's chunk")
+            m.set_counter_fn("dense_hits_cols_total",
+                             _tally("serve.hits_cols", 1),
+                             help="Batch columns whose hit mask the dense "
+                                  "route built: those whose threshold is "
+                                  "finite in float32 (top-k riders' is not)")
 
     def _arena(self):
         """The live sketch arena, re-resolved per call — ingest swaps the

@@ -241,6 +241,100 @@ def test_topk_sweeps_tally_their_query_columns(corpus):
     assert prof.tallies()["sweep.query_cols"] == (2, float(sum(cols)))
 
 
+#: Batch kinds of the hits step: (k, thresholds). ``inf`` is a top-k
+#: rider's threshold (``submit_topk``), or a query read's own +inf. Each
+#: batch ends with an empty query.
+HIT_BATCHES = {
+    "topk": (5, [np.inf] * 5),
+    "mixed": (5, [np.inf, 0.5, np.inf, 0.7, 0.9]),
+    "threshold": (0, [0.5, 0.7, 0.9, 0.5, 0.3]),
+    "query_inf": (0, [0.5, np.inf, 0.9, 0.7, np.inf]),
+}
+HIT_ROUTES = {"dense": ("dense", "jnp"), "fused": ("pruned", "jnp"),
+              "host_pruned": ("pruned", "numpy")}
+
+
+def _hit_batch(recs, kind):
+    k, thr = HIT_BATCHES[kind]
+    return k, list(recs[20:24]) + [np.zeros(0, np.int64)], thr
+
+
+@pytest.mark.parametrize("route", list(HIT_ROUTES))
+@pytest.mark.parametrize("kind", list(HIT_BATCHES))
+def test_hits_skip_columns_whose_threshold_is_inf(corpus, kind, route):
+    """Every route answers a +inf column with no hits and every finite
+    column as the full mask over the sweep's scores does, bit for bit;
+    top-k answers do not depend on the riders' thresholds."""
+    from repro.planner import prune
+
+    recs, index, _, _, _ = corpus
+    k, queries, thr = _hit_batch(recs, kind)
+    plan, backend = HIT_ROUTES[route]
+    sharded = _sharded(index, backend)
+    out = sharded.serve_batch(queries, thr, k, plan=plan)
+    assert sharded.last_plan.path == plan
+    # Every dense score is finite, the empty query's too: a column at
+    # +inf can have no hits.
+    scores = np.asarray(sharded._dense_scores(sharded._sketch(queries)))
+    assert np.all(np.isfinite(scores))
+    want = prune.threshold_hits_packed(scores[:sharded.num_records], thr)
+    for t, w, o in zip(thr, want, out):
+        assert o["hits"].dtype == np.int64
+        if np.isinf(t):
+            assert len(o["hits"]) == 0
+        else:
+            np.testing.assert_array_equal(o["hits"], w)
+    if kind != "topk":
+        assert sum(len(o["hits"]) for o in out) > 0
+    tops = sharded.serve_batch(queries, None, k, plan=plan)
+    for o, w in zip(out, tops):
+        np.testing.assert_array_equal(o["topk_ids"], w["topk_ids"])
+        np.testing.assert_array_equal(o["topk_scores"], w["topk_scores"])
+
+
+def test_topk_batch_builds_no_hit_mask(corpus, monkeypatch):
+    """A dense batch whose thresholds are all +inf neither compares nor
+    packs a mask, yet opens its ``serve.hits`` stage."""
+    from repro import obs
+    from repro.obs.profile import StageProfiler
+    from repro.planner import route
+
+    def no_mask(*_):
+        raise AssertionError("hit mask built for a top-k batch")
+
+    monkeypatch.setattr(route, "threshold_hits_packed", no_mask)
+    monkeypatch.setattr(route, "threshold_mask", no_mask)
+    recs, index, _, _, _ = corpus
+    k, queries, thr = _hit_batch(recs, "topk")
+    prof = StageProfiler()
+    with obs.attach(profiler=prof):
+        out = _sharded(index).serve_batch(queries, thr, k)
+    assert all(len(o["hits"]) == 0 and len(o["topk_ids"]) == k for o in out)
+    assert prof.stages()["serve.hits"].count == 1
+
+
+def test_dense_batches_tally_their_masked_columns(corpus, served):
+    """``serve.hits_cols`` counts each dense batch that reaches the hits
+    step and sums the columns whose mask it built: none of a top-k
+    batch, the finite ones of a mixed batch, all of a threshold batch.
+    /metrics exports the sum; a pruned flush builds no dense mask."""
+    from repro import obs
+    from repro.obs.profile import StageProfiler
+
+    recs, index, _, _, _ = corpus
+    sharded = _sharded(index)
+    prof = StageProfiler()
+    with obs.attach(profiler=prof):
+        for kind in ("topk", "mixed", "threshold"):
+            k, queries, thr = _hit_batch(recs, kind)
+            sharded.serve_batch(queries, thr, k, plan="dense")
+    assert prof.tallies()["serve.hits_cols"] == (3, 0.0 + 3 + 5)
+    for (plan, _), (srv, app, _) in served.items():
+        metrics = parse_prometheus(app.metrics.render())
+        dense = srv.index.last_plan.path == "dense"
+        assert metrics["dense_hits_cols_total"] == (BATCH if dense else 0)
+
+
 @pytest.fixture(scope="module")
 def doors(corpus):
     """The three serving doors over the same records: a ShardedIndex on a
